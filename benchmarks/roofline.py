@@ -27,9 +27,12 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12       # bf16 / chip (TPU v5e)
-HBM_BW = 819e9            # bytes/s / chip
-LINK_BW = 50e9            # bytes/s / link (ICI)
+from repro.obs.costmodel import DEVICE_PEAKS
+
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E["bf16_flops"]     # / chip
+HBM_BW = _V5E["hbm_bw"]             # bytes/s / chip
+LINK_BW = _V5E["ici_link_bw"]       # bytes/s / link (ICI)
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "artifacts", "dryrun")
 

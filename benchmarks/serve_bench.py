@@ -240,6 +240,9 @@ def run_cluster(quick: bool = False, smoke: bool = False, *,
       under per-tenant quotas: the victim's contended p99 must stay within
       2x its isolated p99, and every noisy rejection must be a quota
       rejection (``TenantOverQuota``), never shared-queue overload.
+
+    The replica lifetimes are child processes that each need the device,
+    so on an accelerator this refuses (a chip serves one process at a time).
     """
     import shutil
     import tempfile
@@ -250,10 +253,12 @@ def run_cluster(quick: bool = False, smoke: bool = False, *,
     from repro.core.quant import QuantConfig
     from repro.fsl.pipeline import FSLPipeline
     from repro.models import resnet9
+    from repro.runtime import refuse_child_processes_on_accelerator
     from repro.serve import ServeOverload
     from repro.serve.cluster import (ServeCluster, TenantOverQuota,
                                      TenantRegistry)
 
+    refuse_child_processes_on_accelerator("the cluster cold-start bench")
     results: Dict[str, float] = {}
 
     def emit(metric: str, value) -> None:

@@ -24,9 +24,10 @@ from repro.core import build
 from repro.core.graph import execute
 from repro.core.quant import FixedPointSpec, QuantConfig
 from repro.models import resnet9
+from repro.obs.costmodel import DEVICE_PEAKS
 
 WIDTH = 16
-HBM_BW = 819e9
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["hbm_bw"]
 
 
 def _bench(fn, x, iters=5):

@@ -10,7 +10,9 @@ import argparse
 from repro.core.quant import QuantConfig
 from repro.data.synthetic import SyntheticImages
 from repro.fsl.pipeline import FSLPipeline, evaluate_episodes, pretrain_backbone
+from repro.runtime import use_compile_cache
 
+use_compile_cache()
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=150)
 ap.add_argument("--width", type=int, default=16)

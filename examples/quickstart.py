@@ -15,7 +15,9 @@ from repro.core.quant import fake_quant
 from repro.data.synthetic import SyntheticImages
 from repro.fsl import ncm
 from repro.models import resnet9
+from repro.runtime import use_compile_cache
 
+use_compile_cache()
 WIDTH = 8
 
 # 1. pick a bit-width configuration (the paper's deployment point: conv

@@ -26,8 +26,10 @@ from repro.ckpt import CompileCache
 from repro.core.quant import QuantConfig
 from repro.data.synthetic import SyntheticImages
 from repro.fsl.pipeline import FSLPipeline, pretrain_backbone
+from repro.runtime import use_compile_cache
 from repro.serve.cluster import ServeCluster, TenantOverQuota, TenantRegistry
 
+use_compile_cache()
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=80)
 ap.add_argument("--width", type=int, default=8)

@@ -40,6 +40,9 @@ def main(argv=None):
     ap.add_argument("--legacy", action="store_true",
                     help="run the pre-PR-10 eager decode-loop demo instead")
     args, rest = ap.parse_known_args(argv)
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache()
     if args.legacy:
         return legacy_main(rest)
 

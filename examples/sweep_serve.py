@@ -20,8 +20,10 @@ import numpy as np
 
 from repro.data.synthetic import SyntheticImages
 from repro.explore import SweepFarm, publish_frontier, select_knee
+from repro.runtime import use_compile_cache
 from repro.serve import ArtifactRegistry, ServeEngine
 
+use_compile_cache()
 ap = argparse.ArgumentParser()
 ap.add_argument("--cache-dir", default=".farm_cache")
 ap.add_argument("--steps", type=int, default=40)

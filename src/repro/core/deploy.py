@@ -385,14 +385,15 @@ class DeployedModel:
         return rows
 
     def profile(self, example, *, xla: bool = True,
-                backend: Optional[str] = None) -> Dict[str, Any]:
+                device_kind: Optional[str] = None) -> Dict[str, Any]:
         """Per-node FLOPs/bytes/estimated-ms attribution for one batch
         shape, cross-checked against XLA's ``cost_analysis()`` totals —
         see :func:`repro.obs.costmodel.profile_deployed`.  The farm records
         ``totals.est_ms`` into sweep points as ``modeled_ms``."""
         from repro.obs.costmodel import profile_deployed
 
-        return profile_deployed(self, example, xla=xla, backend=backend)
+        return profile_deployed(self, example, xla=xla,
+                                device_kind=device_kind)
 
     def qdq_counts(self) -> Dict[str, int]:
         """Surviving quantize/dequantize nodes and interior round-trip pairs.

@@ -313,7 +313,9 @@ def _ex_im2col(node: Node, x: jax.Array) -> jax.Array:
 
 def _ex_matmul(node: Node, x: jax.Array, w: jax.Array,
                b: Optional[jax.Array] = None) -> jax.Array:
-    y = jnp.matmul(x, w)
+    # full f32 products: the grid emulation is exact only if operands are
+    # not rounded to bf16, which a TPU's default precision does
+    y = jnp.matmul(x, w, precision=jax.lax.Precision.HIGHEST)
     if b is not None:
         y = y + b
     return y
@@ -338,7 +340,8 @@ def _ex_multithreshold(node: Node, x: jax.Array, t: jax.Array) -> jax.Array:
 
 
 def _ex_mvau(node: Node, x: jax.Array, w: jax.Array, t: jax.Array) -> jax.Array:
-    """Fused matmul+threshold — dispatched to the Pallas MVAU kernel."""
+    """Fused matmul+threshold — dispatched to the Pallas MVAU kernel,
+    compiled on a TPU and interpreted elsewhere."""
     from repro.kernels import ops as kops
 
     return kops.mvau(
@@ -346,7 +349,6 @@ def _ex_mvau(node: Node, x: jax.Array, w: jax.Array, t: jax.Array) -> jax.Array:
         out_base=node.attrs.get("out_base", 0),
         out_scale=node.attrs.get("out_scale", 1.0),
         out_bias=node.attrs.get("out_bias", 0.0),
-        interpret=node.attrs.get("interpret", True),
     )
 
 

@@ -17,11 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # promoted out of jax.experimental in newer jax releases
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map  # type: ignore
-
 
 def pipeline_apply(stage_fn: Callable, ws: jax.Array, x: jax.Array,
                    mesh: Mesh, axis: str = None) -> jax.Array:
@@ -71,5 +66,5 @@ def pipeline_apply(stage_fn: Callable, ws: jax.Array, x: jax.Array,
         # only the last stage holds real outputs; sum-broadcast to all
         return jax.lax.psum(jnp.where(is_last, outs, 0), axis)
 
-    return shard_map(worker, mesh=mesh, in_specs=(P(axis), P()),
-                     out_specs=P(), check_rep=False)(ws, x)
+    return jax.shard_map(worker, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P(), check_vma=False)(ws, x)

@@ -178,7 +178,9 @@ class SweepFarm:
     is derived from ``(seed, candidate)`` alone, so results are
     scheduling-independent.  ``mode="process"`` dispatches over a
     spawn-context process pool instead of threads (each child re-imports
-    JAX; the shared cache directory is the only coordination point).
+    JAX; the shared cache directory is the only coordination point).  It
+    runs on the CPU backend only: on an accelerator every child would need
+    the chip this process holds, so it refuses.
     """
 
     def __init__(self, cache_dir: str, *, width: int = 8, steps: int = 120,
@@ -233,6 +235,10 @@ class SweepFarm:
 
         if self.mode == "process" and workers > 1:
             import multiprocessing as mp
+
+            from repro.runtime import refuse_child_processes_on_accelerator
+
+            refuse_child_processes_on_accelerator("SweepFarm(mode='process')")
 
             ctx = mp.get_context("spawn")   # no forked JAX runtime state
             with ProcessPoolExecutor(max_workers=workers,

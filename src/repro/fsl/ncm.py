@@ -37,16 +37,19 @@ def running_update(sums: jax.Array, counts: jax.Array, features: jax.Array,
     Rows are added STRICTLY sequentially in presentation order (lax.scan),
     so folding one batch equals folding the same rows split across any
     number of chunks — the bit-for-bit contract the online store relies on.
+    Each row is L2-normalized inside the step, as a (D,) vector: a norm
+    taken over the whole (N, D) chunk is free to vectorize differently for
+    each N, which moves the last bit with the chunking.
     """
-    f = _l2(features.astype(jnp.float32))
     labels = labels.astype(jnp.int32)
 
     def step(carry, xs):
         s, c = carry
         row, lab = xs
-        return (s.at[lab].add(row), c.at[lab].add(1.0)), None
+        return (s.at[lab].add(_l2(row)), c.at[lab].add(1.0)), None
 
-    (sums, counts), _ = jax.lax.scan(step, (sums, counts), (f, labels))
+    (sums, counts), _ = jax.lax.scan(
+        step, (sums, counts), (features.astype(jnp.float32), labels))
     return sums, counts
 
 
@@ -65,11 +68,29 @@ def class_means(features: jax.Array, labels: jax.Array, n_way: int
     return finalize_means(sums, counts)
 
 
+@jax.jit
+def cosine_sims(query_features: jax.Array, means: jax.Array) -> jax.Array:
+    """(Q, D) queries x (C, D) normalized means -> (Q, C) cosine sims.
+
+    An elementwise product reduced over D, not a matmul: each similarity is
+    then one f32 reduction of D f32 products, in an order XLA's CPU backend
+    keeps for every Q and C.  A GEMM picks its kernel, and so its summation
+    order, from the shapes (a one-row block becomes a matrix-vector
+    product), and on a TPU it rounds f32 operands to bf16 at default
+    precision; either would make a padded, bucketed or sharded head differ
+    from the offline one in the last bits.  On a TPU v5e the reduction (and
+    the norm of ``_l2``) still moves the last bit when Q or C is 1, by up
+    to 3e-8.  Jitted, so an eager caller runs the same fused program as a
+    traced one.
+    """
+    q = _l2(query_features.astype(jnp.float32))
+    return jnp.sum(q[:, None, :] * means.astype(jnp.float32)[None, :, :],
+                   axis=-1)
+
+
 def ncm_classify(query_features: jax.Array, means: jax.Array) -> jax.Array:
     """Nearest mean in cosine distance (== L2 on normalized vectors)."""
-    q = _l2(query_features.astype(jnp.float32))
-    sims = q @ means.T
-    return jnp.argmax(sims, axis=-1)
+    return jnp.argmax(cosine_sims(query_features, means), axis=-1)
 
 
 def ncm_accuracy(query_features: jax.Array, query_labels: jax.Array,

@@ -7,6 +7,9 @@ away.  On TPU the same shape: accumulate the (H·W, C) feature map into a
 in the datapath.
 
 Grid: ``(N, HW/bhw)`` — one image per grid row, spatial chunks innermost.
+The output is laid out ``(N, 1, C)`` so each image's block is a whole
+``(1, C)`` plane: a ``(1, C)`` block of an ``(N, C)`` array would break the
+TPU's (8, 128) tiling rule for every N > 1.
 """
 
 from __future__ import annotations
@@ -34,7 +37,7 @@ def _gap_kernel(x_ref, o_ref, acc_ref, *, n_hw: int, int_path: bool):
 
     @pl.when(h == n_hw - 1)
     def _emit():
-        o_ref[0] = acc_ref[0]
+        o_ref[0] = acc_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bhw", "interpret"))
@@ -44,6 +47,7 @@ def gap_pallas(x: jax.Array, bhw: int = 256, interpret: bool = False) -> jax.Arr
     int_path = jnp.issubdtype(x.dtype, jnp.integer)
     out_dtype = jnp.int32 if int_path else jnp.float32
     xf = x.reshape(n, h * w, c)
+    bhw = min(bhw, -(-(h * w) // 8) * 8)      # a sublane multiple
     pad = (-xf.shape[1]) % bhw
     if pad:
         xf = jnp.pad(xf, ((0, 0), (0, pad), (0, 0)))
@@ -54,8 +58,8 @@ def gap_pallas(x: jax.Array, bhw: int = 256, interpret: bool = False) -> jax.Arr
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((1, bhw, c), lambda i, j: (i, j, 0))],
-        out_specs=pl.BlockSpec((1, c), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, c), out_dtype),
+        out_specs=pl.BlockSpec((1, 1, c), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, c), out_dtype),
         scratch_shapes=[pltpu.VMEM((1, c), out_dtype)],
         interpret=interpret,
-    )(xf)
+    )(xf).reshape(n, c)

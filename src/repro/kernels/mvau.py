@@ -12,86 +12,86 @@ DRAM between matmul and activation.  The TPU analogue implemented here:
   exactly as in the FINN dataflow edge, eliminating the HBM round-trip of the
   intermediate.
 
-Two datapaths, selected by operand dtype:
-  int8 × int8 → int32 accumulate, int32 thresholds  (the FINN path proper)
-  f32/bf16    → f32 accumulate, f32 thresholds      (QAT-grid floats)
+Two kernels:
+  ``mvau_int_pallas``  int8 × int8 → int32 accumulate, int32 thresholds
+                       (the FINN path proper, on the int8 MXU)
+  ``mvau_pallas``      f32 × f32 → f32 accumulate at full precision, f32
+                       thresholds (QAT-grid floats)
 
 Grid: ``(M/bm, N/bn, K/bk)`` with K innermost (sequential accumulation).
+Threshold tables enter the kernels transposed, as ``(L, N)``: each level is
+then one ``(1, bn)`` row that broadcasts over the ``bm`` accumulator rows, so
+the compare-count needs no ``(bm, bn, L)`` slab and VMEM stays bounded at any
+L (8-bit activations have L = 255).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_THRESH_CHUNK = 32  # L is tiled so the (bm, bn, chunk) compare fits VMEM
+
+def _count_levels(acc: jax.Array, t_ref, n_levels: int) -> jax.Array:
+    """``Σ_l 1[acc ≥ t_l]`` for a (bm, bn) accumulator against an (L, bn)
+    threshold block, one level row at a time."""
+    def level(i, counts):
+        return counts + (acc >= t_ref[pl.ds(i, 1), :]).astype(jnp.int32)
+
+    return jax.lax.fori_loop(0, n_levels, level,
+                             jnp.zeros(acc.shape, jnp.int32))
 
 
 def _mvau_kernel(x_ref, w_ref, t_ref, o_ref, acc_ref, *,
                  n_k: int, n_levels: int, out_base: float, out_scale: float,
-                 out_bias: float, int_path: bool, out_dtype):
+                 out_bias: float):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]
-    w = w_ref[...]
-    if int_path:
-        acc_ref[...] += jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    else:
-        acc_ref[...] += jax.lax.dot_general(
-            x.astype(jnp.float32), w.astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    # f32 products at full precision: a TPU's default rounds f32 operands
+    # to bf16, which is inexact for grid values wider than 8 bits
+    acc_ref[...] += jax.lax.dot_general(
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(k == n_k - 1)
     def _activate():
-        acc = acc_ref[...]                      # (bm, bn)
-        counts = jnp.zeros(acc.shape, jnp.int32)
-        # Chunked compare-count: thresholds block is (bn, L); compare the
-        # (bm, bn, chunk) slab and reduce, keeping VMEM bounded for large L
-        # (e.g. 8-bit activations -> L = 255).
-        for l0 in range(0, n_levels, _THRESH_CHUNK):
-            l1 = min(l0 + _THRESH_CHUNK, n_levels)
-            t = t_ref[:, l0:l1]                 # (bn, chunk)
-            cmp = acc[:, :, None] >= t[None, :, :]
-            counts += jnp.sum(cmp.astype(jnp.int32), axis=-1)
+        counts = _count_levels(acc_ref[...], t_ref, n_levels)
         y = out_scale * (out_base + counts.astype(jnp.float32)) + out_bias
-        o_ref[...] = y.astype(out_dtype)
+        o_ref[...] = y
 
 
-def _unpack_int4_block(w: jax.Array) -> jax.Array:
-    """In-register nibble unpack: packed (bk, bn//2) int8 → (bk, bn) codes.
+def unpack_int4_block(w: jax.Array) -> jax.Array:
+    """In-register nibble unpack: packed (bk, bn) int8 → (bk, 2·bn) int32
+    codes in [-8, 7].
 
-    Low nibble holds the even output channel (quant.pack_int4's layout).
-    Runs on the VPU inside the kernel, so packed weights go HBM→VMEM at
-    half the bytes and never exist unpacked outside the register file.
+    Low nibble holds the even output channel (quant.pack_int4's layout);
+    the shift pairs sign-extend each nibble.  Runs on the VPU inside the
+    kernel, so packed weights go HBM→VMEM at half the bytes and never exist
+    unpacked outside VMEM.
     """
-    p = w.astype(jnp.int32) & 0xFF
-    lo = p & 0xF
-    hi = (p >> 4) & 0xF
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
+    p = w.astype(jnp.int32)
+    lo = (p << 28) >> 28
+    hi = (p << 24) >> 28
     return jnp.stack([lo, hi], axis=-1).reshape(w.shape[0], w.shape[1] * 2)
 
 
 def _mvau_int_kernel(x_ref, w_ref, t_ref, o_ref, acc_ref, *,
-                     n_k: int, n_levels: int, out_base: int, w_packed: bool,
-                     int8_mxu: bool):
+                     n_k: int, n_levels: int, out_base: int, w_packed: bool):
     """Integer MVAU writing int32 codes: the FINN datapath proper.
 
     The int32 accumulator lives in VMEM scratch across the K grid axis; on
     the last K step the sorted per-channel threshold table is applied
-    in-register (chunked compare-count — FINN's unary thresholding, exactly
-    what the HW MVAU does) and only the narrow output code is written back.
-    The wide accumulator never touches HBM.
+    in-register (level-by-level compare-count — FINN's unary thresholding,
+    exactly what the HW MVAU does) and only the narrow output code is
+    written back.  The wide accumulator never touches HBM.
     """
     k = pl.program_id(2)
 
@@ -99,38 +99,31 @@ def _mvau_int_kernel(x_ref, w_ref, t_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]
     w = w_ref[...]
     if w_packed:
-        w = _unpack_int4_block(w)
-    if int8_mxu:
-        acc_ref[...] += jax.lax.dot_general(
-            x.astype(jnp.int8), w.astype(jnp.int8),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    else:
-        acc_ref[...] += jax.lax.dot_general(
-            x.astype(jnp.int32), w.astype(jnp.int32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        w = unpack_int4_block(w).astype(jnp.int8)
+    acc_ref[...] += jax.lax.dot_general(
+        x_ref[...], w, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _activate():
-        acc = acc_ref[...]                      # (bm, bn) int32
-        counts = jnp.zeros(acc.shape, jnp.int32)
-        for l0 in range(0, n_levels, _THRESH_CHUNK):
-            l1 = min(l0 + _THRESH_CHUNK, n_levels)
-            t = t_ref[:, l0:l1]                 # (bn, chunk) int32
-            cmp = acc[:, :, None] >= t[None, :, :]
-            counts += jnp.sum(cmp.astype(jnp.int32), axis=-1)
-        o_ref[...] = out_base + counts
+        o_ref[...] = out_base + _count_levels(acc_ref[...], t_ref, n_levels)
 
 
-def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
+def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     pad = (-x.shape[axis]) % mult
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
+    return jnp.pad(x, widths)
+
+
+def _thresholds_lanes(thresholds: jax.Array, n_pad: int, big) -> jax.Array:
+    """(N, L) table -> (L, N + n_pad): levels on sublanes, channels on
+    lanes; padded channels get a threshold no accumulator reaches."""
+    return jnp.pad(thresholds.T, ((0, 0), (0, n_pad)), constant_values=big)
 
 
 @functools.partial(
@@ -141,35 +134,31 @@ def mvau_pallas(x: jax.Array, w: jax.Array, thresholds: jax.Array,
                 out_base: float = 0.0, out_scale: float = 1.0,
                 out_bias: float = 0.0, bm: int = 128, bn: int = 128,
                 bk: int = 128, interpret: bool = False) -> jax.Array:
-    """Fused ``multithreshold(x @ w)``; see module docstring.
+    """Fused ``multithreshold(x @ w)`` on f32 grid values; see module
+    docstring.
 
-    x: (M, K); w: (K, N); thresholds: (N, L) (per-tensor (L,) is broadcast by
-    the ops.py wrapper).  int8 operands take the integer datapath (int32
-    thresholds required); anything else runs f32.
+    x: (M, K) f32; w: (K, N) f32; thresholds: (N, L) f32 (per-tensor (L,)
+    is broadcast by the ops.py wrapper).  Output: (M, N) f32.
     """
     if x.ndim != 2 or w.ndim != 2 or thresholds.ndim != 2:
         raise ValueError("mvau_pallas expects 2-D x, w and (N, L) thresholds")
-    m, kdim = x.shape
-    _, n = w.shape
+    m, _ = x.shape
+    n = w.shape[1]
     n_levels = thresholds.shape[1]
-    int_path = x.dtype == jnp.int8 and w.dtype == jnp.int8
-    out_dtype = jnp.float32
 
     # Pad to block multiples (K zero-pad is exact for matmul; padded N/M
     # rows/cols are sliced off below; +inf thresholds keep padded-channel
     # counts at zero rather than garbage).
     xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
     wp = _pad_to(_pad_to(w, 0, bk), 1, bn)
-    big = jnp.iinfo(jnp.int32).max if thresholds.dtype == jnp.int32 else jnp.inf
-    tp = _pad_to(thresholds, 0, bn, value=big)
+    tp = _thresholds_lanes(thresholds, wp.shape[1] - n, jnp.inf)
     mp, kp = xp.shape
     np_ = wp.shape[1]
     grid = (mp // bm, np_ // bn, kp // bk)
 
     kernel = functools.partial(
         _mvau_kernel, n_k=grid[2], n_levels=n_levels, out_base=float(out_base),
-        out_scale=float(out_scale), out_bias=float(out_bias),
-        int_path=int_path, out_dtype=out_dtype)
+        out_scale=float(out_scale), out_bias=float(out_bias))
 
     out = pl.pallas_call(
         kernel,
@@ -177,13 +166,11 @@ def mvau_pallas(x: jax.Array, w: jax.Array, thresholds: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn, n_levels), lambda i, j, k: (j, 0)),
+            pl.BlockSpec((n_levels, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.int32 if int_path else jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(xp, wp, tp)
     return out[:m, :n]
@@ -196,48 +183,49 @@ def mvau_int_pallas(x: jax.Array, w: jax.Array, thresholds_int: jax.Array,
                     out_base: int = 0, w_packed: bool = False,
                     bm: int = 128, bn: int = 128, bk: int = 128,
                     interpret: bool = False) -> jax.Array:
-    """Fused integer MVAU: int32 code output, packed-int4 weight compute.
+    """Fused integer MVAU on the int8 MXU: int32 code output.
 
-    x: (M, K) integer codes; w: (K, N) dense codes or (K, N//2) packed int4
-    pairs (``w_packed=True`` — unpacked in-register, never materialized);
-    thresholds_int: (N, L) sorted int32.  Output: (M, N) int32 codes
-    ``out_base + Σᵢ 1[acc ≥ Tᵢ]``.  int8 operands take the MXU; wider codes
-    multiply on the VPU at int32.
+    x: (M, K) int8 codes; w: (K, N) int8 codes, or (K, N//2) packed int4
+    pairs (``w_packed=True``: unpacked to int8 in VMEM, never materialized
+    in HBM); thresholds_int: (N, L) sorted int32.  Output: (M, N) int32
+    codes ``out_base + Σᵢ 1[acc ≥ Tᵢ]``.  ``bn`` is the lane width of a
+    weight block as stored, so a packed block yields ``2·bn`` channels.
+    Wider codes have no MXU path; ``ops.kernel_dispatch`` keeps them off
+    this kernel.
     """
     if x.ndim != 2 or w.ndim != 2 or thresholds_int.ndim != 2:
         raise ValueError(
             "mvau_int_pallas expects 2-D x, w and (N, L) thresholds")
-    m, kdim = x.shape
+    if x.dtype != jnp.int8 or w.dtype != jnp.int8:
+        raise TypeError(f"mvau_int_pallas takes int8 codes, got x "
+                        f"{x.dtype} and w {w.dtype}")
+    m, _ = x.shape
     n = w.shape[1] * (2 if w_packed else 1)
     n_levels = thresholds_int.shape[1]
-    int8_mxu = x.dtype == jnp.int8 and w.dtype == jnp.int8 and not w_packed
+    bn_out = 2 * bn if w_packed else bn
 
-    if w_packed and bn % 2:
-        raise ValueError("packed weights need an even bn")
-    wn_block = bn // 2 if w_packed else bn
     xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
-    wp = _pad_to(_pad_to(w, 0, bk), 1, wn_block)
-    big = jnp.iinfo(jnp.int32).max
-    tp = _pad_to(thresholds_int, 0, bn, value=big)
+    wp = _pad_to(_pad_to(w, 0, bk), 1, bn)
     mp, kp = xp.shape
     np_ = wp.shape[1] * (2 if w_packed else 1)
-    grid = (mp // bm, np_ // bn, kp // bk)
+    tp = _thresholds_lanes(thresholds_int, np_ - n, jnp.iinfo(jnp.int32).max)
+    grid = (mp // bm, np_ // bn_out, kp // bk)
 
     kernel = functools.partial(
         _mvau_int_kernel, n_k=grid[2], n_levels=n_levels,
-        out_base=int(out_base), w_packed=w_packed, int8_mxu=int8_mxu)
+        out_base=int(out_base), w_packed=w_packed)
 
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, wn_block), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn, n_levels), lambda i, j, k: (j, 0)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((n_levels, bn_out), lambda i, j, k: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn_out), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn_out), jnp.int32)],
         interpret=interpret,
     )(xp, wp, tp)
     return out[:m, :n]

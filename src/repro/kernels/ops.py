@@ -54,10 +54,11 @@ def mvau(x: jax.Array, w: jax.Array, thresholds: jax.Array,
 def mvau_int(x_codes: jax.Array, w_codes: jax.Array, thresholds_int: jax.Array,
              out_base: int = 0, interpret: Optional[bool] = None,
              w_packed: bool = False) -> jax.Array:
-    """Integer MVAU: integer codes in, int32 codes out (FINN path).
+    """Integer MVAU on the int8 MXU: int8 codes in, int32 codes out (FINN
+    path).
 
     ``w_packed`` feeds the (K, N//2) packed-int4 buffer straight to the
-    kernel, which unpacks nibbles in-register — the packed form the
+    kernel, which unpacks nibbles to int8 in VMEM — the packed form the
     lowering stores is also the compute form.
     """
     interpret = default_interpret() if interpret is None else interpret
@@ -101,10 +102,12 @@ def kernel_dispatch(node, emulated: bool,
     construction what actually runs.  Labels:
 
     * ``fused-pallas`` — compiled fused integer MVAU (int8 MXU / packed-int4
-      unpack in-register, thresholds applied on the accumulator in VMEM);
+      unpack in VMEM, thresholds applied on the accumulator in VMEM); only
+      for codes that fit int8 (``int8_ok``) — the MXU has no wider path;
     * ``int8-dot``   — XLA ``dot_general`` at int8 with int32 accumulation;
     * ``f32-gemm``   — exact integer compute through the backend's f32 GEMM
-      (proof obligation ``acc_f32_exact`` discharged at lowering time);
+      at full precision (proof obligation ``acc_f32_exact`` discharged at
+      lowering time);
     * ``ref-oracle`` — naive exact integer fallback;
     * ``pallas``     — compiled float Pallas kernel;
     * ``fast-count`` / ``int-shift`` — vectorized integer threshold count /
@@ -113,8 +116,8 @@ def kernel_dispatch(node, emulated: bool,
     """
     op = node.op
     if op == "mvau_int":
-        if not emulated and (n_levels is None
-                             or n_levels <= _PALLAS_MAX_LEVELS):
+        if not emulated and node.attrs.get("int8_ok") and (
+                n_levels is None or n_levels <= _PALLAS_MAX_LEVELS):
             return "fused-pallas"
         if node.attrs.get("acc_f32_exact"):
             return "f32-gemm"
@@ -164,13 +167,9 @@ def graph_op_impls(interpret: Optional[bool] = None):
         base = node.attrs.get("out_base", 0)
         disp = kernel_dispatch(node, emulated, n_levels=t.shape[-1])
         if disp == "fused-pallas":
-            packed = bool(node.attrs.get("w_packed"))
-            if node.attrs.get("int8_ok"):
-                x = x.astype(jnp.int8)
-                if not packed:
-                    w = w.astype(jnp.int8)
-            return mvau_int(x, w, t, out_base=base, interpret=False,
-                            w_packed=packed)
+            return mvau_int(x.astype(jnp.int8), w.astype(jnp.int8), t,
+                            out_base=base, interpret=False,
+                            w_packed=bool(node.attrs.get("w_packed")))
         if node.attrs.get("w_packed"):
             w = Q.unpack_int4(w)
         # exact fast path through the f32 GEMM when lowering proved the

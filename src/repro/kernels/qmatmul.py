@@ -9,6 +9,8 @@ scale is applied once to the f32 accumulator at the end (linearity — the
 dequant multiply leaves the inner loop entirely).
 
 Grid: ``(M/bm, N/bn, K/bk)``, K innermost; f32 VMEM scratch accumulator.
+A packed int4 weight block is ``bk × bn`` bytes as stored (128 lanes wide,
+as the TPU's tiling rule asks) and unpacks to ``2·bn`` output channels.
 """
 
 from __future__ import annotations
@@ -19,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mvau import unpack_int4_block
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k: int, bits: int,
@@ -31,16 +35,7 @@ def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k: int, bits: int,
 
     x = x_ref[...].astype(jnp.bfloat16)
     w = w_ref[...]
-    if bits == 4:
-        # (bk, bn//2) int8 -> (bk, bn) int4 codes, sign-extended.
-        p = w.astype(jnp.int32)
-        lo = p & 0xF
-        hi = (p >> 4) & 0xF
-        lo = jnp.where(lo >= 8, lo - 16, lo)
-        hi = jnp.where(hi >= 8, hi - 16, hi)
-        w_codes = jnp.stack([lo, hi], axis=-1).reshape(w.shape[0], w.shape[1] * 2)
-    else:
-        w_codes = w.astype(jnp.int32)
+    w_codes = unpack_int4_block(w) if bits == 4 else w.astype(jnp.int32)
     acc_ref[...] += jax.lax.dot_general(
         x, w_codes.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -67,7 +62,8 @@ def qmatmul_pallas(x: jax.Array, w_codes: jax.Array, scale: jax.Array,
     """``x @ dequant(w_codes)`` with per-output-channel scale.
 
     x: (M, K) bf16/f32; w_codes: (K, N) int8 when bits==8, (K, N//2) packed
-    int8 when bits==4; scale: (N,) f32.
+    int8 when bits==4; scale: (N,) f32.  ``bn`` is the lane width of a
+    weight block as stored.
     """
     if bits not in (4, 8):
         raise ValueError(f"bits must be 4 or 8, got {bits}")
@@ -77,13 +73,13 @@ def qmatmul_pallas(x: jax.Array, w_codes: jax.Array, scale: jax.Array,
         raise ValueError(f"scale must be ({n},), got {scale.shape}")
     out_dtype = x.dtype
 
-    bn_eff = bn // 2 if bits == 4 else bn  # packed width of a weight block
+    bn_out = 2 * bn if bits == 4 else bn   # output channels per weight block
     xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
-    wp = _pad_to(_pad_to(w_codes, 0, bk), 1, bn_eff)
-    sp = _pad_to(scale.astype(jnp.float32).reshape(1, n), 1, bn)
+    wp = _pad_to(_pad_to(w_codes, 0, bk), 1, bn)
+    sp = _pad_to(scale.astype(jnp.float32).reshape(1, n), 1, bn_out)
     mp, kp = xp.shape
     np_ = wp.shape[1] * (2 if bits == 4 else 1)
-    grid = (mp // bm, np_ // bn, kp // bk)
+    grid = (mp // bm, np_ // bn_out, kp // bk)
 
     kernel = functools.partial(_qmm_kernel, n_k=grid[2], bits=bits,
                                out_dtype=out_dtype)
@@ -92,12 +88,12 @@ def qmatmul_pallas(x: jax.Array, w_codes: jax.Array, scale: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn_eff), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, bn_out), lambda i, j, k: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn_out), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn_out), jnp.float32)],
         interpret=interpret,
     )(xp, wp, sp)
     return out[:m, :n]

@@ -25,7 +25,8 @@ def mvau(x: jax.Array, w: jax.Array, thresholds: jax.Array,
     thresholds: (L,) or (N, L).  Output: float32 codes
     ``out_scale * (out_base + Σᵢ 1[y ≥ Tᵢ]) + out_bias``.
     """
-    y = jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32))
+    y = jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     return quant.multithreshold(y, thresholds, out_base, out_scale, out_bias)
 
 
@@ -63,10 +64,13 @@ def matmul_int_fast(x_codes: jax.Array, w_codes: jax.Array,
     (``acc_f32_exact``), computing the code matmul in f32 is EXACT: every
     intermediate is an integer exactly representable in the f32 mantissa,
     so the truncating cast back to int32 is the identity on the true sum.
+    That needs f32 operands: a TPU's default precision rounds them to bf16,
+    which is inexact above ±256, hence ``HIGHEST``.
     """
     if acc_f32_exact:
         acc = jnp.matmul(x_codes.astype(jnp.float32),
-                         w_codes.astype(jnp.float32))
+                         w_codes.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
         return acc.astype(jnp.int32)
     return matmul_int(x_codes, w_codes)
 
@@ -187,8 +191,12 @@ def gap(x: jax.Array) -> jax.Array:
 # Decode-workload attention (PR 10): shared by the graph interpreter, the
 # compiled DeployedModel and models.lm.decode_step_ref — ONE definition so
 # "bit-for-bit with the interpreter" is a property of the code, not a hope.
-# All math is f32; no GQA broadcast (callers assert n_kv_heads == n_heads).
+# All math is f32 — the einsums ask for HIGHEST precision, since a TPU's
+# default rounds f32 operands to bf16 — and there is no GQA broadcast
+# (callers assert n_kv_heads == n_heads).
 # ---------------------------------------------------------------------------
+_F32 = jax.lax.Precision.HIGHEST
+
 def attn_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                 k_cache: jax.Array, v_cache: jax.Array, pos: jax.Array,
                 heads: int):
@@ -211,11 +219,11 @@ def attn_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     qh = q.astype(jnp.float32).reshape(B, heads, hd)
     kh = kc.astype(jnp.float32).reshape(B, C, heads, hd)
     vh = vc.astype(jnp.float32).reshape(B, C, heads, hd)
-    s = jnp.einsum("bhd,bchd->bhc", qh, kh) / math.sqrt(hd)
+    s = jnp.einsum("bhd,bchd->bhc", qh, kh, precision=_F32) / math.sqrt(hd)
     live = jnp.arange(C, dtype=jnp.int32)[None, None, :] <= pos[:, None, None]
     s = jnp.where(live, s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhc,bchd->bhd", w, vh).reshape(B, D)
+    out = jnp.einsum("bhc,bchd->bhd", w, vh, precision=_F32).reshape(B, D)
     return out.astype(q.dtype), kc, vc
 
 
@@ -227,10 +235,11 @@ def attn_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     qh = q.astype(jnp.float32).reshape(B, S, heads, hd)
     kh = k.astype(jnp.float32).reshape(B, S, heads, hd)
     vh = v.astype(jnp.float32).reshape(B, S, heads, hd)
-    s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / math.sqrt(hd)
+    s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh, precision=_F32) / math.sqrt(hd)
     causal = (jnp.arange(S, dtype=jnp.int32)[None, :]
               <= jnp.arange(S, dtype=jnp.int32)[:, None])
     s = jnp.where(causal[None, None, :, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", w, vh).reshape(B, S, D)
+    out = jnp.einsum("bhqk,bkhd->bqhd", w, vh,
+                     precision=_F32).reshape(B, S, D)
     return out.astype(q.dtype)

@@ -38,7 +38,7 @@ from repro.dist.sharding import (
     tree_param_shardings,
 )
 from repro.launch import specs as S
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.steps import (
     make_decode_step,
     make_prefill_step,
@@ -203,10 +203,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     import re as _re
     mm = _re.search(r"mesh(\d+)x(\d+)", variant or "")
     if mm and not multi_pod:
-        import jax as _jax
         d_, m_ = int(mm.group(1)), int(mm.group(2))
         assert d_ * m_ == 256, "single-pod mesh must keep 256 chips"
-        mesh = _jax.make_mesh((d_, m_), ("data", "model"))
+        mesh = make_mesh((d_, m_), ("data", "model"))
     cfg, serving_bits, rules, notes = apply_variant(cfg, variant, mesh)
     kind = S.SHAPES[shape_name]["kind"]
     # >50B archs in multi-pod mode: FSDP widens across pods (ZeRO-3) —

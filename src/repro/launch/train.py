@@ -24,6 +24,7 @@ from repro.dist.sharding import (
     tree_param_shardings,
 )
 from repro.dist.straggler import StragglerMonitor
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step, model_module
 from repro.models.common import get_config
 from repro.optim import adamw_init
@@ -49,9 +50,9 @@ def main(argv=None):
     mod = model_module(cfg)
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((max(n_dev // 2, 1), min(n_dev, 2)),
-                         ("data", "model")) if n_dev > 1 else \
-        jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((max(n_dev // 2, 1), min(n_dev, 2)),
+                     ("data", "model")) if n_dev > 1 else \
+        make_mesh((1, 1), ("data", "model"))
 
     params = mod.init_params(jax.random.PRNGKey(0), cfg)
     opt = adamw_init(params)

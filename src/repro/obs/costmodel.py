@@ -10,9 +10,10 @@ inferred for the given batch and produces one row per node:
   (``graph.dtypes`` FixedPointSpec bits when annotated — packed int4 counts
   at 0.5 B/elem — else f32), initializers at their actual ``nbytes``;
 * **est_ms** — single-node roofline bound, ``max(flops/peak, bytes/bw)``,
-  with per-backend peak/bandwidth constants (TPU v5e numbers match
-  ``benchmarks/roofline.py``; CPU constants are deliberately coarse — the
-  *ranking* is what the farm consumes, not the absolute value);
+  with the peaks of the device kind it runs on from :data:`DEVICE_PEAKS`
+  (int8 peak for int8 matmuls, bf16 peak otherwise; the CPU row is
+  deliberately coarse — the *ranking* is what the farm consumes, not the
+  absolute value);
 * **kernel** — the dispatch label from
   :meth:`DeployedModel.dispatch_table`, so a node whose cost model says
   "cheap" but whose kernel says ``ref-oracle`` is visible in one row.
@@ -32,15 +33,32 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["BACKEND_ROOFLINE", "profile_deployed", "render_profile"]
+__all__ = ["DEVICE_PEAKS", "device_peaks", "profile_deployed",
+           "render_profile"]
 
-# (peak FLOP/s, memory bandwidth B/s).  TPU v5e values mirror
-# benchmarks/roofline.py; "cpu" is a generic server-core ballpark.
-BACKEND_ROOFLINE = {
-    "tpu": (197e12, 819e9),
-    "gpu": (60e12, 1000e9),
-    "cpu": (1e11, 2e10),
+# Peak rates per device, keyed by ``jax.Device.device_kind`` — the one
+# table the cost model and ``benchmarks/roofline.py`` read.  "TPU v5 lite"
+# (TPU v5e): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 393 TOP/s int8, 819 GB/s HBM, 1,600 Gbit/s of interconnect over 4 links.
+# "cpu": a generic server-core ballpark that only ranks CPU-backend
+# profiles; it is never a device number.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bw": 819e9, "ici_link_bw": 50e9},
+    "cpu": {"bf16_flops": 1e11, "int8_ops": 1e11, "hbm_bw": 2e10,
+            "ici_link_bw": 0.0},
 }
+
+
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of ``device_kind``.  A device missing from
+    :data:`DEVICE_PEAKS` is an error, never a fallback to another row."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; add "
+                       f"its published peaks to DEVICE_PEAKS") from None
+
 
 _MATMUL_OPS = {"matmul", "matmul_int", "mvau", "mvau_int"}
 _THRESHOLD_OPS = {"multithreshold", "multithreshold_int"}
@@ -124,17 +142,18 @@ def _xla_totals(dm, x) -> Optional[Dict[str, float]]:
 
 
 def profile_deployed(dm, example, *, xla: bool = True,
-                     backend: Optional[str] = None) -> Dict[str, Any]:
+                     device_kind: Optional[str] = None) -> Dict[str, Any]:
     """Per-node FLOPs/bytes/estimated-ms table for one batch shape.
 
     ``example`` is a batched input (same contract as ``dm(example)``).
-    Returns ``{"batch", "backend", "nodes": [row...], "totals", "xla"}``;
-    rows carry ``share`` of total modeled time so the table reads as an
-    attribution, and ``kernel`` from the live dispatch table.
+    Returns ``{"batch", "device_kind", "nodes": [row...], "totals",
+    "xla"}``; rows carry ``share`` of total modeled time so the table reads
+    as an attribution, and ``kernel`` from the live dispatch table.
     """
     x = jnp.asarray(example)
-    be = backend or jax.default_backend()
-    peak, bw = BACKEND_ROOFLINE.get(be, BACKEND_ROOFLINE["cpu"])
+    kind = device_kind or jax.devices()[0].device_kind
+    peaks = device_peaks(kind)
+    bw = peaks["hbm_bw"]
 
     g = dm.graph.copy()
     if len(dm.input_names) != 1:
@@ -145,6 +164,8 @@ def profile_deployed(dm, example, *, xla: bool = True,
     rows = []
     for node in g.nodes:
         flops = _node_flops(g, node)
+        peak = (peaks["int8_ops"] if node.op in _MATMUL_OPS
+                and node.attrs.get("int8_ok") else peaks["bf16_flops"])
         nbytes = (sum(_tensor_bytes(g, t) for t in node.inputs)
                   + sum(_tensor_bytes(g, t) for t in node.outputs))
         est_ms = max(flops / peak, nbytes / bw) * 1e3
@@ -166,7 +187,7 @@ def profile_deployed(dm, example, *, xla: bool = True,
     }
     return {
         "batch": int(x.shape[0]) if x.ndim else 1,
-        "backend": be,
+        "device_kind": kind,
         "nodes": rows,
         "totals": totals,
         "xla": _xla_totals(dm, x) if xla else None,
@@ -178,7 +199,8 @@ def render_profile(prof: Dict[str, Any], top: int = 0) -> str:
     rows = sorted(prof["nodes"], key=lambda r: -r["est_ms"])
     if top:
         rows = rows[:top]
-    lines = [f"profile: batch={prof['batch']} backend={prof['backend']} "
+    lines = [f"profile: batch={prof['batch']} "
+             f"device_kind={prof['device_kind']} "
              f"modeled {prof['totals']['est_ms']*1e3:.1f} us "
              f"({prof['totals']['flops']/1e6:.2f} MFLOP, "
              f"{prof['totals']['bytes']/1e6:.3f} MB)"]

@@ -12,7 +12,9 @@ against the replicated queries, and the blocks concatenate along the class
 axis.  Row-block sharding never splits a reduction: every similarity is
 still one dot product over the full feature dim on one device, so the
 sharded head is **bit-for-bit** equal to the serial one — sharding moves
-work, never numerics (the ``repro.dist`` contract).
+work, never numerics (the ``repro.dist`` contract).  On four TPU v5e chips
+this holds at two rows per chip (``chip_smoke.py --chips 4``); a one-row
+block can move the last bit there (see :func:`repro.fsl.ncm.cosine_sims`).
 
 On a single device :func:`repro.dist.sharding.serve_mesh` returns ``None``
 and the head degrades to the exact serial computation the
@@ -28,7 +30,6 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import act_sharding
@@ -57,18 +58,18 @@ class ShardedNCMHead:
     def __init__(self, devices: Optional[List] = None):
         self.mesh = serve_mesh(devices)
         self.n_dev = 1 if self.mesh is None else self.mesh.shape[self.AXIS]
-        self._serial = jax.jit(lambda q, m: ncm._l2(q) @ m.T)
+        self._serial = jax.jit(ncm.cosine_sims)
         self._sharded = None
         if self.mesh is not None:
             mesh = self.mesh
 
-            @partial(shard_map, mesh=mesh,
+            @partial(jax.shard_map, mesh=mesh,
                      in_specs=(P(), P(self.AXIS, None)),
                      out_specs=P(None, self.AXIS))
             def blocks(q, m_block):
                 # per-device: full-D dots against this device's row block —
                 # identical per-element reduction to the serial head
-                return ncm._l2(q) @ m_block.T
+                return ncm.cosine_sims(q, m_block)
 
             def sharded(q, m):
                 q = act_sharding.constrain(q, self.QUERY_RULE)
@@ -76,27 +77,34 @@ class ShardedNCMHead:
 
             self._sharded = jax.jit(sharded)
 
-    def sims(self, query_features, means) -> np.ndarray:
-        """(Q, D) queries × (C, D) prototype means -> (Q, C) cosine sims,
-        bit-for-bit equal to the serial ``_l2(q) @ means.T``."""
-        q = jnp.asarray(query_features, jnp.float32)
+    def place(self, means) -> jax.Array:
+        """The (C, D) prototype rows zero-padded to a multiple of the device
+        count and split by rows over the mesh — the operand the sharded
+        program reads (on one device: the rows as they are)."""
         m = jnp.asarray(means, jnp.float32)
-        c = m.shape[0]
-        if self.mesh is None or c == 0:
-            return np.asarray(self._serial(q, m))
-        pad = (-c) % self.n_dev
+        if self.mesh is None:
+            return m
+        pad = (-m.shape[0]) % self.n_dev
         if pad:
             m = jnp.concatenate(
                 [m, jnp.zeros((pad, m.shape[1]), m.dtype)], axis=0)
+        return jax.device_put(
+            m, NamedSharding(self.mesh,
+                             prototype_spec(int(m.shape[0]), self.mesh)))
+
+    def sims(self, query_features, means) -> np.ndarray:
+        """(Q, D) queries × (C, D) prototype means -> (Q, C) cosine sims,
+        bit-for-bit equal to the serial ``ncm.cosine_sims``."""
+        q = jnp.asarray(query_features, jnp.float32)
+        c = np.shape(means)[0]
+        if self.mesh is None or c == 0:
+            return np.asarray(self._serial(q, jnp.asarray(means, jnp.float32)))
         # bind the replicated-queries rule for the trace; the constraint is
         # the identity when unbound, so this is a layout hint, not a
         # correctness dependency
         rule = NamedSharding(self.mesh, P())
-        m = jax.device_put(
-            m, NamedSharding(self.mesh,
-                             prototype_spec(int(m.shape[0]), self.mesh)))
         with act_sharding.rules({self.QUERY_RULE: rule}):
-            out = self._sharded(q, m)
+            out = self._sharded(q, self.place(means))
         return np.asarray(out[:, :c])
 
 

@@ -463,11 +463,13 @@ def greedy_generate(engine: Any, prompts: Sequence[Sequence[int]],
 def build_decode_artifact(params: Any, cfg: Any, *, datapath: str = "int",
                           capacities: Sequence[int] = (32, 64),
                           fuse: bool = True, verify: bool = True,
-                          with_prefill: bool = False) -> DecodeArtifact:
+                          with_prefill: bool = False,
+                          interpret: Optional[bool] = None) -> DecodeArtifact:
     """Compile ``(params, cfg)`` through the ``lm-decode`` recipe into a
     servable :class:`DecodeArtifact` (golden-IO verified against the graph
     interpreter when ``verify`` — for ``datapath="int"`` that check is
-    bit-for-bit)."""
+    bit-for-bit).  ``interpret=True`` builds the off-TPU datapath on any
+    backend — the CPU reference a chip run is compared with."""
     from repro.core import deploy
     from repro.models import lm            # registers the lm-decode recipe
 
@@ -475,12 +477,14 @@ def build_decode_artifact(params: Any, cfg: Any, *, datapath: str = "int",
     feeds = lm.example_decode_feeds(cfg, batch=2, capacity=int(caps[0]))
     dm = deploy.compile({"params": params, "cfg": cfg}, cfg.quant,
                         recipe="lm-decode", datapath=datapath, fuse=fuse,
-                        verify_feeds=feeds if verify else None)
+                        verify_feeds=feeds if verify else None,
+                        interpret=interpret)
     dmp = None
     if with_prefill:
         gp = lm.export_prefill_graph(params, cfg)
         pf = lm.example_prefill_feeds(cfg) if verify else None
         dmp = deploy.compile(gp, cfg.quant, recipe="lm-decode",
-                             datapath=datapath, fuse=fuse, verify_feeds=pf)
+                             datapath=datapath, fuse=fuse, verify_feeds=pf,
+                             interpret=interpret)
     return DecodeArtifact(dm, cfg.d_model, capacities=caps, vocab=cfg.vocab,
                           dm_prefill=dmp)

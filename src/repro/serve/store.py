@@ -108,9 +108,9 @@ class PrototypeStore:
             return self._means, tuple(self._order)
 
     def _sims(self, q: np.ndarray, means: np.ndarray) -> np.ndarray:
-        # jnp end to end so a served batch agrees bitwise with an offline
-        # ncm_classify over the same rows (same XLA reduction, same shapes)
-        return np.asarray(ncm._l2(jnp.asarray(q)) @ jnp.asarray(means).T)
+        # the offline head's own function, so a served batch agrees bitwise
+        # with ncm_classify over the same rows
+        return np.asarray(ncm.cosine_sims(jnp.asarray(q), jnp.asarray(means)))
 
     def classify(self, query_features
                  ) -> Tuple[List[Hashable], np.ndarray]:

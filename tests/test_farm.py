@@ -198,6 +198,22 @@ def test_process_pool_dispatch_matches_serial(tmp_path):
     assert again.cached == [True, True]
 
 
+def test_process_pool_refuses_on_accelerator(tmp_path, monkeypatch):
+    """A chip serves one process at a time and this one already holds it:
+    on an accelerator, mode='process' refuses before starting a child."""
+    import types
+
+    import jax
+
+    tpu = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda *a: [tpu])
+    farm = SweepFarm(str(tmp_path / "p"), workers=2, mode="process",
+                     width=2, steps=1, verbose=False)
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        farm.run([(3, 2), (4, 4)])
+    assert not (tmp_path / "p").exists() or not any((tmp_path / "p").iterdir())
+
+
 # ---------------------------------------------------------------------------
 # publish: sweep → serve the knee, bit for bit
 # ---------------------------------------------------------------------------

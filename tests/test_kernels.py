@@ -72,8 +72,8 @@ def test_mvau_int_fused_kernel_odd_shapes(m, k, n, levels):
     applied in-register on the int32 accumulator) is bit-exact against the
     pure oracle at ragged tile shapes, and so is the f32-exact GEMM fast
     path the CPU backend serves from."""
-    x = RNG.integers(0, 16, size=(m, k)).astype(np.int32)
-    w = RNG.integers(-8, 8, size=(k, n)).astype(np.int32)
+    x = RNG.integers(0, 16, size=(m, k)).astype(np.int8)
+    w = RNG.integers(-8, 8, size=(k, n)).astype(np.int8)
     t = np.sort(RNG.integers(-500, 4000, size=(n, levels)),
                 axis=1).astype(np.int32)
     want = np.asarray(ref.mvau_int(jnp.asarray(x), jnp.asarray(w),
@@ -93,7 +93,7 @@ def test_mvau_int_packed_int4_in_kernel_unpack():
     compute layout: the kernel unpacks nibbles in-register and matches the
     unpacked oracle bit-for-bit."""
     m, k, n = 6, 36, 32
-    x = RNG.integers(0, 16, size=(m, k)).astype(np.int32)
+    x = RNG.integers(0, 16, size=(m, k)).astype(np.int8)
     w = RNG.integers(-8, 8, size=(k, n)).astype(np.int32)
     t = np.sort(RNG.integers(-500, 3000, size=(n, 15)), axis=1).astype(np.int32)
     wp = np.asarray(quant.pack_int4(jnp.asarray(w)))
@@ -104,6 +104,34 @@ def test_mvau_int_packed_int4_in_kernel_unpack():
                                   jnp.asarray(t), out_base=-3,
                                   interpret=True, w_packed=True))
     np.testing.assert_array_equal(want, got)
+
+
+@pytest.mark.parametrize("attrs,levels,want", [
+    ({"int8_ok": True}, 15, "fused-pallas"),
+    ({"int8_ok": True, "w_packed": True}, 15, "fused-pallas"),
+    ({"int8_ok": True}, 255, "fused-pallas"),
+    ({"int8_ok": True, "acc_f32_exact": True}, 1023, "f32-gemm"),
+    ({"int8_ok": False, "acc_f32_exact": True}, 15, "f32-gemm"),
+    ({"int8_ok": False}, 15, "ref-oracle"),
+])
+def test_kernel_dispatch_on_chip_keeps_wide_codes_off_the_mxu(attrs, levels,
+                                                               want):
+    """On a TPU only int8 codes take the fused kernel (the MXU has no wider
+    integer path); wider codes go to the exact GEMM or the oracle."""
+    from repro.core.graph import Node
+
+    node = Node("mvau_int", ["x", "w", "t"], ["y"], dict(attrs))
+    assert ops.kernel_dispatch(node, emulated=False, n_levels=levels) == want
+    assert ops.kernel_dispatch(node, emulated=True,
+                               n_levels=levels) != "fused-pallas"
+
+
+def test_mvau_int_kernel_takes_int8_codes_only():
+    x = jnp.zeros((4, 8), jnp.int32)
+    w = jnp.zeros((8, 8), jnp.int8)
+    t = jnp.zeros((8, 3), jnp.int32)
+    with pytest.raises(TypeError, match="int8"):
+        ops.mvau_int(x, w, t, interpret=True)
 
 
 def test_threshold_counts_fast_matches_dense():

@@ -28,7 +28,8 @@ def test_pipeline_parallel_matches_sequential():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.dist.pipeline import pipeline_apply
-        mesh = jax.make_mesh((4,), ("pipe",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pipe",))
         n_stages, n_micro, mb, d = 4, 8, 2, 16
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (n_stages, d, d)) * 0.3
@@ -70,6 +71,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         from repro.models.common import get_config
         from repro.models.testing import reduce_config
         from repro.models import lm
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_train_step
         from repro.dist.sharding import (tree_param_shardings,
             tree_batch_shardings, tree_opt_shardings)
@@ -77,7 +79,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         import dataclasses
 
         cfg = reduce_config(get_config("qwen2.5-3b"), grad_accum=2)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         params = lm.init_params(jax.random.PRNGKey(0), cfg)
         opt = adamw_init(params)
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 4, 16), 0, cfg.vocab)
@@ -121,12 +123,13 @@ def test_sharded_decode_runs():
         from repro.models.common import get_config
         from repro.models.testing import reduce_config
         from repro.models import lm
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_decode_step
         from repro.dist.sharding import (tree_param_shardings,
             tree_batch_shardings, tree_cache_shardings)
 
         cfg = reduce_config(get_config("qwen3-14b"), compute_dtype="float32")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         params = lm.init_params(jax.random.PRNGKey(0), cfg)
         cache = lm.init_cache(cfg, B=4, max_len=32, dtype=jnp.float32)
         batch = {"tokens": jnp.zeros((4, 1), jnp.int32)}
@@ -158,6 +161,7 @@ def test_mini_dryrun_8dev():
         from repro.models.common import get_config
         from repro.models.testing import reduce_config
         from repro.models import lm
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_train_step
         from repro.launch import hlo_analysis
         from repro.dist.sharding import (tree_param_shardings,
@@ -166,7 +170,7 @@ def test_mini_dryrun_8dev():
 
         cfg = reduce_config(get_config("grok-1-314b"), grad_accum=2,
                             moe_capacity_factor=1.25)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         params_sds = jax.eval_shape(
             lambda: lm.init_params(jax.random.PRNGKey(0), cfg))
         psh = tree_param_shardings(params_sds, mesh)

@@ -383,6 +383,22 @@ def test_deployed_model_profile_cost_table(traced_compile):
     assert "modeled" in text and nodes[0]["op"] in text
 
 
+def test_profile_peaks_keyed_by_device_kind(traced_compile):
+    """The roofline peaks come from the row of the device kind profiled;
+    a kind with no row raises instead of borrowing another's peaks."""
+    from repro.obs.costmodel import device_peaks
+
+    dm, _ = traced_compile
+    x = np.zeros((1, 16, 16, 3), np.float32)
+    cpu = dm.profile(x, xla=False)
+    v5e = dm.profile(x, xla=False, device_kind="TPU v5 lite")
+    assert cpu["device_kind"] == "cpu" and v5e["device_kind"] == "TPU v5 lite"
+    assert v5e["totals"]["est_ms"] < cpu["totals"]["est_ms"]
+    assert device_peaks("TPU v5 lite")["int8_ops"] == 393e12
+    with pytest.raises(KeyError, match="no peak rates"):
+        dm.profile(x, xla=False, device_kind="TPU v99")
+
+
 @pytest.mark.slow
 def test_run_point_records_modeled_cost():
     from repro.explore.sweep import run_point
