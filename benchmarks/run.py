@@ -12,12 +12,10 @@ serial, resume speedup), and the cluster benchmark to ``BENCH_pr6.json``
 (cold start vs compile-cache restore, overload tail latency, noisy-neighbor
 isolation), and the fused-datapath benchmark to ``BENCH_pr7.json`` (fused
 int artifact vs f32 vs unfused int at b1/b16, serve-side rps rows, interior
-quantize/dequantize census), and the observability benchmark to
-``BENCH_pr8.json`` (serve-throughput overhead of the tracing spine with the
-tracer disabled vs enabled, plus span-coverage accounting), and the
-per-layer search benchmark to ``BENCH_pr9.json`` (best searched
-mixed-precision plan vs best uniform grid point on the acc/bytes frontier,
-bit-exact registry serve of the searched artifact), and the decode
+quantize/dequantize census), and the per-layer search benchmark to
+``BENCH_pr9.json`` (best searched mixed-precision plan vs best uniform grid
+point on the acc/bytes frontier, bit-exact registry serve of the searched
+artifact), and the decode
 benchmark to ``BENCH_pr10.json`` (int vs f32 LM decode-step latency at
 b1/b16, engine greedy tokens/s, zero-retrace and bitwise-vs-eager gates)
 — the machine-readable perf trajectory successive PRs diff against.
@@ -39,7 +37,7 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default="",
                     help="comma list: table2,table3,fig5,roofline,compile,"
-                         "serve,cluster,farm,pr7,pr8,pr9,pr10")
+                         "serve,cluster,farm,pr7,pr9,pr10")
     ap.add_argument("--bench-json", default=None,
                     help="where the compile benchmark dict is written "
                          "(default: repo-root BENCH_pr2.json for full runs; "
@@ -98,10 +96,6 @@ def main(argv=None) -> None:
         bench_io.write_bench_json(res, benchmark="pr7",
                                   basename="BENCH_pr7.json",
                                   quick=args.quick)
-    if want("pr8"):
-        from benchmarks import obs_bench
-        obs_bench.write_json(obs_bench.run(quick=args.quick),
-                             quick=args.quick)
     if want("pr9"):
         from benchmarks import search_bench
         search_bench.write_json(search_bench.run(quick=args.quick),

@@ -143,6 +143,11 @@ def fsl_phases(seed: int, seconds: dict) -> None:
                 for art in registry.names():
                     eng.submit_register(f"novel{way}", shots,
                                         artifact=art).result(120)
+        # the head's programs at this class count, for every query bucket
+        primed = eng.warmup(img=IMG)
+        head = ServeEngine.HEAD_TRACES
+        check({**primed, head: traces[head]} == traces,
+              "no backbone trace from registers")
         single, batch = {}, {}
         with timed("classify 15 single frames x 2 artifacts", seconds):
             for art in registry.names():
@@ -152,8 +157,9 @@ def fsl_phases(seed: int, seconds: dict) -> None:
             for art in registry.names():
                 batch[art] = eng.submit_classify(queries,
                                                  artifact=art).result(120)
-        print(f"  traces: {traces} at warmup, {eng.trace_counts()} after")
-        check(eng.trace_counts() == traces, "no trace after warmup")
+        print(f"  traces: {traces} at warmup, {primed} with the classes "
+              f"registered, {eng.trace_counts()} after")
+        check(eng.trace_counts() == primed, "no trace after warmup")
     for art in registry.names():
         ids_single = [r.class_ids[0] for r in single[art]]
         sims_single = np.concatenate([r.sims for r in single[art]])

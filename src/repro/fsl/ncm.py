@@ -68,6 +68,9 @@ def class_means(features: jax.Array, labels: jax.Array, n_way: int
     return finalize_means(sums, counts)
 
 
+_traces = [0]          # traces of the head; see trace_count()
+
+
 @jax.jit
 def cosine_sims(query_features: jax.Array, means: jax.Array) -> jax.Array:
     """(Q, D) queries x (C, D) normalized means -> (Q, C) cosine sims.
@@ -83,9 +86,17 @@ def cosine_sims(query_features: jax.Array, means: jax.Array) -> jax.Array:
     to 3e-8.  Jitted, so an eager caller runs the same fused program as a
     traced one.
     """
+    _traces[0] += 1            # runs at trace time only (jit above)
     q = _l2(query_features.astype(jnp.float32))
     return jnp.sum(q[:, None, :] * means.astype(jnp.float32)[None, :, :],
                    axis=-1)
+
+
+def trace_count() -> int:
+    """How often this process traced :func:`cosine_sims`: once per new
+    (queries, classes, feature dim) shape, wherever it is called from (the
+    serving store, the sharded head, the offline episodes)."""
+    return _traces[0]
 
 
 def ncm_classify(query_features: jax.Array, means: jax.Array) -> jax.Array:

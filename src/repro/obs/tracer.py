@@ -169,8 +169,7 @@ class Tracer:
         Deliberately flat: the event dict is built and handed to the
         exporter right here (no helper hops) — this call sits on the serve
         worker's critical path between dequeue and the next backbone exec,
-        and each layer of Python call overhead showed up directly in the
-        enabled-overhead benchmark."""
+        where each layer of Python call overhead costs throughput."""
         if not self.enabled:
             return ""
         exp = self.exporter
@@ -193,8 +192,7 @@ class Tracer:
         (``span_id``/``status``/``attrs`` may be None for auto-ID/"ok"/{}).
         One tracer call per coalesced batch instead of ~3 per request: the
         per-call overhead and the wall-clock read are paid once, and the
-        event loop stays tight — this is what keeps the enabled tracing
-        cost inside the <= 5% serve-throughput budget."""
+        event loop stays tight."""
         if not self.enabled:
             return
         exp = self.exporter

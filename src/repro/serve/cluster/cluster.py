@@ -108,12 +108,12 @@ class ServeCluster:
         """Warm every replica.  The first engine's sweep compiles (or
         cache-restores) each distinct backbone executable set exactly once;
         the artifacts are shared, so the remaining replicas' sweeps find
-        every bucket already present and cost microseconds."""
-        counts: Dict[str, Optional[int]] = {}
+        every bucket already present and cost microseconds.  Returns
+        :meth:`trace_counts`."""
         for eng in list(self.engines):
-            counts = eng.warmup(img=img, cache=self.compile_cache)
+            eng.warmup(img=img, cache=self.compile_cache)
         self._warm_img = img
-        return counts
+        return self.trace_counts()
 
     def add_replica(self, warm: bool = True) -> ServeEngine:
         """Scale out (or stand in for a restarted replica): a new engine
@@ -212,6 +212,8 @@ class ServeCluster:
 
     # -- observability ------------------------------------------------------
     def trace_counts(self) -> Dict[str, Optional[int]]:
+        """Backbone traces per artifact (each replica's
+        ``ServeEngine.trace_counts`` adds the NCM head's)."""
         return self.registry.trace_counts()
 
     def metrics_snapshot(self) -> Dict[str, Any]:

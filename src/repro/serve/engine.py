@@ -39,6 +39,7 @@ from concurrent.futures import Future
 from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
 
 from repro.core.deploy import normalize_buckets, pow2_buckets
+from repro.fsl import ncm
 from repro.obs import get_tracer
 from repro.serve.metrics import ServeMetrics
 from repro.serve.registry import ArtifactRegistry
@@ -86,6 +87,9 @@ class _Request:
 class ServeEngine:
     """Dynamic-batching server over an :class:`ArtifactRegistry`."""
 
+    #: the :meth:`trace_counts` key of the NCM head's traces
+    HEAD_TRACES = "ncm_head"
+
     def __init__(self, registry: ArtifactRegistry, *,
                  max_batch: int = 64, max_queue: int = 256,
                  batch_wait_ms: float = 2.0,
@@ -119,6 +123,9 @@ class ServeEngine:
         self._tenant_lock = threading.Lock()
         self._tenant_queued: Dict[Hashable, int] = {}
         self._pending: Optional[_Request] = None     # coalescer carry slot
+        # (start, end, rows) of the batch the worker last filled: the
+        # adapter turns it into the call's ``serve.fill`` span
+        self._fill: Optional[Tuple[float, float, int]] = None
         self._stop = threading.Event()
         self._worker: Optional[threading.Thread] = None
         if start:
@@ -194,7 +201,13 @@ class ServeEngine:
         return self.trace_counts()
 
     def trace_counts(self) -> Dict[str, Optional[int]]:
-        return self.registry.trace_counts()
+        """Backbone traces per artifact, plus the NCM head's traces under
+        :attr:`HEAD_TRACES` (process-wide: one jitted head serves every
+        store).  A flat dict across load is the zero-retrace check; the
+        head traces once more for each new class count."""
+        counts = self.registry.trace_counts()
+        counts[self.HEAD_TRACES] = ncm.trace_count()
+        return counts
 
     # -- admission ----------------------------------------------------------
     def submit(self, kind: str, payload: Any, *,
@@ -411,6 +424,7 @@ class ServeEngine:
                         self._fail(r, e)
 
     def _next_batch(self) -> Optional[List[_Request]]:
+        t_fill = time.perf_counter()
         first = self._pending
         self._pending = None
         while first is None:
@@ -438,6 +452,7 @@ class ServeEngine:
                 break
             batch.append(nxt)
             total += nxt.n
+        self._fill = (t_fill, time.perf_counter(), total)
         return batch
 
     def _process(self, batch: List[_Request]) -> None:

@@ -175,7 +175,9 @@ class FSLAdapter(ArtifactAdapter):
             x = np.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
             padded, n_real, bucket = pad_to_bucket(x, engine.buckets)
             t_x0 = time.perf_counter()
-            feats = np.asarray(pairs[0][0].feats(padded))[:n_real]
+            out = pairs[0][0].feats(padded)
+            t_xr = time.perf_counter()      # the call returned: dispatched
+            feats = np.asarray(out)[:n_real]
             t_x1 = time.perf_counter()
             engine.metrics.record_batch(n_real, bucket)
         except Exception as e:                        # noqa: BLE001
@@ -185,17 +187,30 @@ class FSLAdapter(ArtifactAdapter):
         for r in reqs:
             r.t_exec1 = t_x1
         tr = engine.tracer
+        call = None            # (trace, span) of this call's serve.batch
+        heads: Optional[list] = None        # per classify run, when traced
         if tr.enabled:
-            # one batch-scope span on its own trace (the padding-overhead
-            # view), plus queue/coalesce/exec children on each request's
-            # trace — all post-hoc from timestamps the worker already
-            # holds, pushed in ONE record_many call so the per-event cost
-            # stays a tight loop instead of 3 tracer calls per request
-            evs = [("serve.batch", t_g0, t_x1, tr.new_trace("batch"),
-                    None, None, None,
+            # one batch-scope span per backbone call on its own trace (the
+            # padding-overhead view) with the call's own phases as its
+            # children, plus queue/coalesce/exec children on each
+            # request's trace — all post-hoc from timestamps the worker
+            # already holds, pushed in ONE record_many call so the
+            # per-event cost stays a tight loop instead of 3 tracer calls
+            # per request
+            btrace = tr.new_trace("batch")
+            call = (btrace, btrace + "-00")
+            fill = engine._fill             # set by the worker's _next_batch
+            heads = []
+            evs = [("serve.batch", t_g0, t_x1, btrace, None, call[1], None,
                     {"n_real": n_real, "bucket": bucket,
                      "padded": bucket - n_real, "requests": len(reqs),
-                     "artifact": pairs[0][0].name})]
+                     "artifact": pairs[0][0].name}),
+                   ("serve.exec.dispatch", t_x0, t_xr, btrace, call[1],
+                    None, None, None),
+                   ("serve.exec.wait", t_xr, t_x1, btrace, call[1],
+                    None, None, None),
+                   ("serve.fill", fill[0], fill[1], btrace, call[1], None,
+                    None, {"rows": fill[2]})]
             for art, r in pairs:
                 root = r.trace + "-00"
                 evs.append(("serve.queue", r.t_enq, r.t_deq, r.trace,
@@ -224,16 +239,23 @@ class FSLAdapter(ArtifactAdapter):
             if not run:
                 return
             lo, hi = run[0][1], run[-1][2]
+            t_h0 = time.perf_counter()
             try:
                 ids, sims = art.store.classify(feats[lo:hi])
+                status = "ok"
             except Exception as exc:                  # noqa: BLE001
+                status = f"error:{type(exc).__name__}"
+                t_h1 = time.perf_counter()
                 for r, _, _ in run:
                     engine._fail(r, exc)
-                run.clear()
-                return
-            for r, s, e in run:
-                engine._fulfill(r, ClassifyResult(
-                    ids[s - lo:e - lo], sims[s - lo:e - lo], art.name))
+            else:
+                t_h1 = time.perf_counter()
+                for r, s, e in run:
+                    engine._fulfill(r, ClassifyResult(
+                        ids[s - lo:e - lo], sims[s - lo:e - lo], art.name))
+            if heads is not None:
+                heads.append((t_h0, t_h1, time.perf_counter(), hi - lo,
+                              len(run), status))
             run.clear()
 
         off = 0
@@ -254,6 +276,16 @@ class FSLAdapter(ArtifactAdapter):
                 continue
             engine._fulfill(r, out)
         flush_run()
+        if heads:
+            # per classify run: the NCM head (``store.classify``), then its
+            # futures resolved, client callbacks included
+            evs = []
+            for t_h0, t_h1, t_f1, rows, n_req, status in heads:
+                evs.append(("serve.head", t_h0, t_h1, call[0], call[1],
+                            None, status, {"rows": rows}))
+                evs.append(("serve.fulfil", t_h1, t_f1, call[0], call[1],
+                            None, None, {"requests": n_req}))
+            tr.record_many(evs)
 
 
 _DEFAULT_FSL = FSLAdapter()

@@ -292,6 +292,134 @@ def test_engine_rejection_still_emits_root_span():
     assert roots and roots[-1]["status"] == "rejected:stopped"
 
 
+# ---------------------------------------------------------------------------
+# per-call spans of the worker's batch cycle, and the head's trace counter
+# ---------------------------------------------------------------------------
+def _end(e):
+    return e["t0"] + e["dur_ms"] * 1e-3
+
+
+def _serve_one_batch(tracer):
+    """Eight rows queued before the worker starts, so they coalesce into ONE
+    backbone call: register c0, two classifies, register c1, a classify of
+    three frames -- two classify runs split by the register.  Returns the
+    answers in submit order."""
+    reg = ArtifactRegistry()
+    reg.register("toy", _toy_feats, default=True)
+    rng = np.random.default_rng(3)
+
+    def frames(n):
+        return rng.random((n, IMG, IMG, 3), np.float32)
+
+    eng = ServeEngine(reg, max_batch=8, batch_wait_ms=1.0, tracer=tracer,
+                      start=False)
+    futs = [eng.submit_register("c0", frames(2)),
+            eng.submit_classify(frames(1)), eng.submit_classify(frames(1)),
+            eng.submit_register("c1", frames(1)),
+            eng.submit_classify(frames(3))]
+    eng.start()
+    try:
+        return [f.result(timeout=30) for f in futs]
+    finally:
+        eng.stop()
+
+
+def _call_spans():
+    """The traced batch's ``serve.batch`` span and its children by name."""
+    tr, ring = _traced_pair()
+    _serve_one_batch(tr)
+    ev = ring.events()
+    (batch,) = [e for e in ev if e["name"] == "serve.batch"]
+    kids = {}
+    for e in ev:
+        if e["trace"] == batch["trace"] and e is not batch:
+            kids.setdefault(e["name"], []).append(e)
+    execs = {(e["t0"], e["dur_ms"]) for e in ev if e["name"] == "serve.exec"}
+    return batch, kids, execs
+
+
+def test_each_backbone_call_emits_one_span_set_on_its_batch_trace():
+    batch, kids, _ = _call_spans()
+    assert {n: len(v) for n, v in kids.items()} == {
+        "serve.fill": 1, "serve.exec.dispatch": 1, "serve.exec.wait": 1,
+        "serve.head": 2, "serve.fulfil": 2}          # one per classify run
+    assert all(e["parent"] == batch["span"]
+               for v in kids.values() for e in v)
+    assert batch["attrs"]["n_real"] == 8
+    assert kids["serve.fill"][0]["attrs"] == {"rows": 8}
+    assert [e["attrs"]["rows"] for e in kids["serve.head"]] == [2, 3]
+    assert [e["attrs"]["requests"] for e in kids["serve.fulfil"]] == [2, 1]
+    assert all(e["status"] == "ok" for e in kids["serve.head"])
+
+
+def test_dispatch_and_wait_tile_the_exec_span():
+    batch, kids, execs = _call_spans()
+    ((x0, x_ms),) = execs               # every request shares the call's
+    (d,), (w,) = kids["serve.exec.dispatch"], kids["serve.exec.wait"]
+    assert d["t0"] == x0
+    assert w["t0"] == pytest.approx(_end(d), abs=1e-9)
+    assert _end(w) == pytest.approx(x0 + x_ms * 1e-3, abs=1e-9)
+    assert d["dur_ms"] + w["dur_ms"] == pytest.approx(x_ms, abs=1e-6)
+    # serve.batch keeps its interval: it ends where the exec span ends
+    assert _end(batch) == pytest.approx(_end(w), abs=1e-9)
+
+
+def test_fill_precedes_the_batch_and_head_follows_the_exec():
+    batch, kids, _ = _call_spans()
+    (fill,), (w,) = kids["serve.fill"], kids["serve.exec.wait"]
+    assert fill["t0"] < _end(fill) <= batch["t0"]
+    heads, fulfils = kids["serve.head"], kids["serve.fulfil"]
+    for h, f in zip(heads, fulfils):
+        assert h["t0"] >= _end(w) - 1e-9
+        assert f["t0"] == pytest.approx(_end(h), abs=1e-9)
+    assert heads[1]["t0"] >= _end(fulfils[0]) - 1e-9
+
+
+def test_disabled_tracer_emits_nothing_and_answers_the_same():
+    ring = RingBufferExporter()
+    off = _serve_one_batch(Tracer(exporter=ring, enabled=False))
+    on = _serve_one_batch(_traced_pair()[0])
+    assert ring.events() == []
+    assert off[0] == on[0] == 2 and off[3] == on[3] == 1
+    for a, b in zip(off[1:3] + off[4:], on[1:3] + on[4:]):
+        assert a.class_ids == b.class_ids
+        np.testing.assert_array_equal(a.sims, b.sims)
+
+
+def _toy13_feats(x):
+    """A fake backbone with 13 features: a width no other head in the
+    suite sees, so the process-wide head counter meets fresh shapes."""
+    x = np.asarray(x, np.float32)
+    return x.reshape(x.shape[0], -1)[:, :13]
+
+
+def test_head_trace_counter_flat_after_warmup_one_per_class_count():
+    from repro.fsl import ncm
+
+    reg = ArtifactRegistry()
+    reg.register("toy13", _toy13_feats, default=True)
+    rng = np.random.default_rng(5)
+
+    def frames(n):
+        return rng.random((n, IMG, IMG, 3), np.float32)
+
+    head = ServeEngine.HEAD_TRACES
+    with ServeEngine(reg, max_batch=8, batch_wait_ms=1.0) as eng:
+        for c in ("c0", "c1"):
+            eng.submit_register(c, frames(2)).result(timeout=30)
+        before = ncm.trace_count()
+        base = eng.warmup(img=IMG)       # the head at 2 classes, buckets 1-8
+        assert base == {"toy13": None, head: ncm.trace_count()}
+        assert base[head] - before == 4
+        for n in range(1, 9):            # every bucket, exact and padded
+            eng.submit_classify(frames(n)).result(timeout=30)
+        assert eng.trace_counts() == base
+        eng.submit_register("c2", frames(1)).result(timeout=30)
+        res = eng.submit_classify(frames(1)).result(timeout=30)
+        assert res.sims.shape == (1, 3)
+        assert eng.trace_counts() == {**base, head: base[head] + 1}
+
+
 def test_cluster_propagates_one_trace_id():
     from repro.serve.cluster import ServeCluster, TenantRegistry
 
