@@ -295,20 +295,30 @@ class Graph:
 # ---------------------------------------------------------------------------
 # Interpreter
 # ---------------------------------------------------------------------------
-def _ex_im2col(node: Node, x: jax.Array) -> jax.Array:
-    """NHWC patch extraction -> (N, OH, OW, KH*KW*C). FINN's Conv lowering."""
-    k, s, p = node.attrs["kernel"], node.attrs["stride"], node.attrs["pad"]
+def im2col(x: jax.Array, k: int = 3, stride: int = 1,
+           pad: int = 1) -> jax.Array:
+    """NHWC patch extraction -> (N, OH, OW, KH*KW*C), columns in (kh, kw, c)
+    order. FINN's Conv lowering; the QAT model's convs call it too.
+
+    The k·k windows are static strided ``lax.slice``s of the padded input,
+    joined on the channel axis. Index-array gathers (and jnp's strided
+    ``x[a:b:s]``, which lowers to a gather) become ``while`` loops of dynamic
+    slices on the TPU; slices and a concatenate stay plain fusions."""
     n, h, w, c = x.shape
-    xp = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
-    oh = (h + 2 * p - k) // s + 1
-    ow = (w + 2 * p - k) // s + 1
-    idx_h = (jnp.arange(oh) * s)[:, None] + jnp.arange(k)[None, :]  # (OH,K)
-    idx_w = (jnp.arange(ow) * s)[:, None] + jnp.arange(k)[None, :]  # (OW,K)
-    # gather rows then cols: (N, OH, K, W+2p, C) -> (N, OH, K, OW, K, C)
-    rows = xp[:, idx_h]                      # (N, OH, K, W', C)
-    patches = rows[:, :, :, idx_w]           # (N, OH, K, OW, K, C)
-    patches = patches.transpose(0, 1, 3, 2, 4, 5)  # (N, OH, OW, K, K, C)
-    return patches.reshape(n, oh, ow, k * k * c)
+    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+    oh = (h + 2 * pad - k) // stride + 1
+    ow = (w + 2 * pad - k) // stride + 1
+    return jnp.concatenate(
+        [jax.lax.slice(xp, (0, i, j, 0),
+                       (n, i + (oh - 1) * stride + 1,
+                        j + (ow - 1) * stride + 1, c),
+                       (1, stride, stride, 1))
+         for i in range(k) for j in range(k)], axis=-1)
+
+
+def _ex_im2col(node: Node, x: jax.Array) -> jax.Array:
+    return im2col(x, node.attrs["kernel"], node.attrs["stride"],
+                  node.attrs["pad"])
 
 
 def _ex_matmul(node: Node, x: jax.Array, w: jax.Array,

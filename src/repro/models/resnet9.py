@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quant import QuantConfig, fake_quant, quantize, thresholds_for
-from repro.core.graph import Graph, Node
+from repro.core.graph import Graph, Node, im2col
 
 Params = Dict[str, Any]
 
@@ -102,21 +102,10 @@ def init_params(key, width: int = 64) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# im2col conv (shared by model and graph — exact-match guarantee)
+# im2col conv: ``core.graph.im2col``, the graph executor's own function, so
+# model == graph holds by construction. It builds the patches from static
+# slices: index-array gathers become device loops on the TPU.
 # ---------------------------------------------------------------------------
-def _im2col(x: jax.Array, k: int = 3, stride: int = 1, pad: int = 1):
-    n, h, w, c = x.shape
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    oh = (h + 2 * pad - k) // stride + 1
-    ow = (w + 2 * pad - k) // stride + 1
-    idx_h = (jnp.arange(oh) * stride)[:, None] + jnp.arange(k)[None, :]
-    idx_w = (jnp.arange(ow) * stride)[:, None] + jnp.arange(k)[None, :]
-    rows = xp[:, idx_h]
-    patches = rows[:, :, :, idx_w]
-    patches = patches.transpose(0, 1, 3, 2, 4, 5)
-    return patches.reshape(n, oh, ow, k * k * c)
-
-
 def _maxpool(x: jax.Array, k: int = 2) -> jax.Array:
     n, h, w, c = x.shape
     return x.reshape(n, h // k, k, w // k, k, c).max(axis=(2, 4))
@@ -141,7 +130,7 @@ def forward(params: Params, x: jax.Array, qcfg: Optional[QuantConfig] = None,
         ws = lcfg.weight if lcfg else None
         as_ = lcfg.act if lcfg else None
         w_q = fake_quant(p["w"], ws).reshape(-1, blk["cout"])
-        y = jnp.matmul(_im2col(x), w_q)                   # conv as im2col·W
+        y = jnp.matmul(im2col(x), w_q)                     # conv as im2col·W
         y = y * p["gamma"] + p["beta"]                    # BN affine (folded)
         y = jax.nn.relu(y)
         y = fake_quant(y, as_)

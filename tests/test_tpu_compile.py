@@ -105,6 +105,18 @@ def test_resnet9_paper_width_compiles_for_v5e(datapath, one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 9
 
 
+def test_im2col_compiles_for_v5e_without_loops(one_chip):
+    """resnet9's c1 patches at bucket 64, cast to int8 as the int MVAU's
+    operand: static slices compile to fusions. Index-array gathers compile
+    to a ``while`` loop of dynamic slices here."""
+    from repro.core.graph import im2col
+
+    compiled = _compile(lambda x: im2col(x).astype(I8),
+                        [((64, 32, 32, 64), I32)], one_chip)
+    hlo = compiled.as_text()
+    assert "while" not in hlo and "fusion" in hlo
+
+
 def test_lm_tiny_decode_step_compiles_for_v5e(one_chip):
     """lm-tiny's int decode step, with its two 255-level fused MLP MVAUs on
     the Pallas kernel and the int8 matmuls on the MXU."""
