@@ -6,7 +6,9 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by name:
 
 * ``bench/configs/<config>.json`` -- sizes, engine settings, limits; its
-  ``model`` names the plain reference ``bench/configs/<model>.py``;
+  ``model`` names both the program's build recipe that is served and the
+  plain reference ``bench/configs/<model>.py``, which also counts the
+  model's work (``weight_matmuls``, ``macs_per_frame``);
 * ``bench/traffic/<mix>.json`` -- parameters of :mod:`traffic`;
 * ``bench/layers/<metric>.py`` -- a ``read(run)`` that returns the metric,
   or ``None`` where it finds nothing to read.
@@ -53,6 +55,8 @@ class Cell:
     traffic: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    config: str
+    root: str                   # the checkout its files were found in
 
 
 def load_benchmark(root: str = ROOT) -> Dict[str, Any]:
@@ -89,7 +93,7 @@ def make_cell(bench: Dict[str, Any], config: str, traffic: str,
     reported = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"]
              if m["moves"] in reported and _lists(m, name)]
-    return Cell(name, chips, cfg, mix, e2e, layer)
+    return Cell(name, chips, cfg, mix, e2e, layer, config, root)
 
 
 def load_file(path: str, name: str):
@@ -100,6 +104,7 @@ def load_file(path: str, name: str):
 
 
 def model_module(cfg: Dict[str, Any], root: str = ROOT):
+    """The configuration's plain reference, ``bench/configs/<model>.py``."""
     return load_file(os.path.join(root, "bench", "configs",
                                   f"{cfg['model']}.py"),
                      f"bench_model_{cfg['model']}")
@@ -225,9 +230,24 @@ def quant_config(cfg: Dict[str, Any]):
                        act=FixedPointSpec(**q["act"]))
 
 
+def check_backbone(cell: Cell) -> None:
+    """Raise unless the program registers the build recipe that the cell's
+    configuration names in ``model``."""
+    from repro.core.recipes import list_recipes, recipe
+
+    try:
+        recipe(cell.cfg["model"])
+    except KeyError:
+        raise ValueError(
+            f"configuration {cell.config!r} names model "
+            f"{cell.cfg['model']!r}, which the program registers no build "
+            f"recipe for; registered: {sorted(list_recipes())}") from None
+
+
 class FSLServe:
-    """ServeEngine over one ``FSLPipeline.deploy`` artifact, with the
-    configuration's support set registered and every shape warmed."""
+    """ServeEngine over one ``FSLPipeline.deploy`` artifact of the build
+    recipe the configuration's ``model`` names, with the configuration's
+    support set registered and every shape warmed."""
 
     ARTIFACT = "served"
 
@@ -235,7 +255,8 @@ class FSLServe:
         from repro.fsl.pipeline import FSLPipeline
         from repro.serve import ArtifactRegistry, ServeEngine
 
-        pipe = FSLPipeline(width=int(cfg["width"]), qcfg=quant_config(cfg),
+        pipe = FSLPipeline(arch=cfg["model"], width=int(cfg["width"]),
+                           qcfg=quant_config(cfg),
                            easy_augment=bool(cfg["easy_augment"]))
         self.recorder = Recorder(pipe.deploy(params, datapath=cfg["datapath"]),
                                  KEEP_BATCHES, seed)
@@ -364,7 +385,8 @@ class Session:
         cfg, mix = cell.cfg, cell.traffic
         self.phases: Dict[str, float] = {}       # set-up seconds by phase
         t = time.perf_counter()
-        self.model = model_module(cfg)
+        check_backbone(cell)
+        self.model = model_module(cfg, cell.root)
         self.watch = Watch()
         self.spans = SpanList()
         self.tracer = Tracer(exporter=self.spans, enabled=False)
@@ -589,11 +611,14 @@ def control_readings(cell: Cell, ev: Dict[str, Any], control: str
 # ---------------------------------------------------------------------------
 # what the per-layer readers get
 # ---------------------------------------------------------------------------
-# What the engine's worker was doing, most specific first: the backbone
-# call, the answers, waiting for stragglers, building the batch; else a
+# What the engine's worker was doing, most specific first: waiting for the
+# backbone's output, launching the backbone call, the classify head,
+# resolving the answers, filling the batch; then the enclosing spans (the
+# call, the answers, waiting for stragglers, building the batch); else a
 # request waiting in the queue.
-GAP_PRIORITY = ("serve.exec", "serve.respond", "serve.coalesce", "serve.batch",
-                "serve.queue")
+GAP_PRIORITY = ("serve.exec.wait", "serve.exec.dispatch", "serve.head",
+                "serve.fulfil", "serve.fill", "serve.exec", "serve.respond",
+                "serve.coalesce", "serve.batch", "serve.queue")
 
 
 class TraceRun:
@@ -615,6 +640,10 @@ class TraceRun:
 
     def peaks(self) -> Dict[str, float]:
         return peaks(self.kind)
+
+    def model(self):
+        """The configuration's plain reference, which counts its work."""
+        return model_module(self.cfg, self.cell.root)
 
     def spans(self, name: str) -> List[Dict]:
         return [e for e in self.events if e["name"] == name]

@@ -1,10 +1,11 @@
 """Share of its roofline that the integer MVAU kernel reached in the
 window: the least time the chip could take for every ``mvau_int`` call
-(``work.mvau_int_bound_s`` from the layer shapes at the rows each call was
-fed) over the device time of the ``mvau_int`` kernels in the trace.
+(``work.mvau_int_bound_s`` at the rows each call was fed, of the layer
+shapes that ``weight_matmuls`` of the configuration's reference file gives)
+over the device time of the ``mvau_int`` kernels in the trace.
 
-Each backbone call (one ``serve.batch`` span, padded to its bucket) runs the
-eight conv MVAUs once per forward pass: twice with the flip ensemble.  The
+Each backbone call (one ``serve.batch`` span, padded to its bucket) runs
+every weight matmul once per forward pass: twice with the flip ensemble.  The
 kernels are matched to the call whose span they start in."""
 
 import work
@@ -20,8 +21,8 @@ def read(run):
     calls = run.device.per_span(run.intervals("serve.batch"), KERNEL)
     pk = run.peaks()
     levels = 2 ** cfg["quant"]["act"]["total_bits"] - 1
-    passes = 2 if cfg["easy_augment"] else 1
-    layers = work.resnet9_mvaus(cfg["width"], cfg["img"])
+    passes = work.passes(cfg)
+    layers = run.model().weight_matmuls(cfg)
     bound = seconds = 0.0
     for b, (count, secs) in zip(batches, calls):
         if count != passes * len(layers):

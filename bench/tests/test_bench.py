@@ -34,14 +34,16 @@ DATA = os.path.join(BENCH, "tests", "data")
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
 
 
-def tiny(name: str, clients: int = 8) -> "harness.Cell":
-    """The cell ``<config>.<traffic>`` at width 4 with few buckets and
-    callers (its metrics those of BENCHMARK.json where it is listed)."""
+def tiny(name: str, clients: int = 8, root: str = ROOT) -> "harness.Cell":
+    """The cell ``<config>.<traffic>`` of the checkout at ``root``, at width
+    4 with few buckets and callers (its metrics those of its BENCHMARK.json
+    where it is listed)."""
     import run  # noqa: F401  (sets up the compile cache like the command)
 
     run.configure_jax()
     config, traffic = name.split(".")
-    cell = harness.make_cell(harness.load_benchmark(), config, traffic)
+    cell = harness.make_cell(harness.load_benchmark(root), config, traffic,
+                             root=root)
     cell.cfg["width"] = 4
     cell.cfg["engine"] = dict(cell.cfg["engine"], max_batch=8,
                               buckets=[1, 2, 4, 8])
@@ -185,6 +187,62 @@ def test_control_fails_the_comparison(config):
     assert any(ctl[k] > limits[k] for k in limits), ctl
 
 
+# -- the reference's batch-norm affine and rounding ----------------------------
+def _exact_code(n: int, step: float, gamma, beta, aq) -> int:
+    """ReLU(gamma * n * step + beta) rounded half to even onto the activation
+    grid and saturated, in rational arithmetic."""
+    from fractions import Fraction as F
+
+    y = F(n) * F(step) * F(float(gamma)) + F(float(beta))
+    code = round(max(y, F(0)) * 2 ** aq["frac_bits"])
+    return min(code, 2 ** aq["total_bits"] - 1)
+
+
+def _act_and_step():
+    cfg = harness.resolve(harness.load_benchmark(),
+                          "resnet9-w6a4-int.cams128").cfg
+    aq = cfg["quant"]["act"]
+    step = 2.0 ** -(aq["frac_bits"] + cfg["quant"]["weight"]["frac_bits"])
+    return harness.model_module(cfg), aq, step
+
+
+def test_reference_levels_start_where_exact_rounding_does():
+    model, aq, step = _act_and_step()
+    rng = np.random.default_rng(7)
+    gamma = rng.uniform(0.5, 2.0, 48).astype(np.float32)
+    beta = rng.uniform(-0.5, 0.5, 48).astype(np.float32)
+    levels = model._levels(gamma, beta, step, aq)
+    assert levels.shape == (48, 2 ** aq["total_bits"] - 1)
+    for c in range(48):
+        for q, n in enumerate(levels[c].astype(int), start=1):
+            assert _exact_code(n, step, gamma[c], beta[c], aq) >= q
+            assert _exact_code(n - 1, step, gamma[c], beta[c], aq) < q
+
+
+# The float32 scale and shift (bit patterns) of channel 35 of c3 at seed
+# 2200160032, of channel 471 of c3 at seed 2200160022 and of channel 58 of
+# c0 at seed 936023273, a conv output (as a multiple of the conv's step,
+# 2**-7) at which the affine lies within a float32 ulp of a half-step,
+# 8.5000005, 7.4999996 and 0.50000002 codes, and the code it rounds to.  A
+# float32 affine reads the last as 0.5 exactly, and rounds it to 0.
+TIES = [(1061441829, 3180791088, 367, 9), (1071991298, 3189632696, 145, 7),
+        (1068559507, 1028284928, 7, 1)]
+
+
+@pytest.mark.parametrize("g_bits,b_bits,n,code", TIES,
+                         ids=["up", "down", "first-level"])
+def test_reference_rounds_a_tie_of_the_affine_exactly(g_bits, b_bits, n,
+                                                      code):
+    model, aq, step = _act_and_step()
+    gamma = np.array([g_bits], np.uint32).view(np.float32)
+    beta = np.array([b_bits], np.uint32).view(np.float32)
+    levels = model._levels(gamma, beta, step, aq)[0]
+    assert int((n >= levels).sum()) == code
+    for m in (n - 1, n, n + 1):
+        assert int((m >= levels).sum()) == _exact_code(m, step, gamma[0],
+                                                       beta[0], aq)
+
+
 def test_command_without_tpu_exits_nonzero_and_prints_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
@@ -198,8 +256,18 @@ def test_command_without_tpu_exits_nonzero_and_prints_no_result():
 
 # -- work and peaks ------------------------------------------------------------
 def test_resnet9_macs_per_frame_at_paper_width():
-    assert work.resnet9_macs_per_frame(64, 32) == 379_256_832
-    assert work.frame_ops(64, 32, passes=2) == 4 * 379_256_832
+    cfg = harness.resolve(harness.load_benchmark(),
+                          "resnet9-w6a4-int.cams128").cfg
+    assert (cfg["width"], cfg["img"], cfg["easy_augment"]) == (64, 32, True)
+    model = harness.model_module(cfg)
+    assert model.macs_per_frame(cfg) == 379_256_832
+    assert work.frame_ops(model, cfg) == 4 * 379_256_832
+    # the eight 3x3 convs as im2col matmuls: rows per frame, K, N
+    assert model.weight_matmuls(cfg) == [
+        ("c0", 1024, 27, 64), ("c1", 1024, 576, 128),
+        ("r1a", 256, 1152, 128), ("r1b", 256, 1152, 128),
+        ("c2", 256, 1152, 256), ("c3", 64, 2304, 512),
+        ("r2a", 16, 4608, 512), ("r2b", 16, 4608, 512)]
 
 
 def test_mvau_int_work_matches_a_hand_count():
@@ -342,9 +410,93 @@ def test_added_config_traffic_and_metric_files_are_found_by_name(tmp_path):
 
 def test_idle_gaps_are_named_by_what_the_worker_was_doing():
     spans = [("serve.queue", 0.0, 10.0), ("serve.batch", 1.0, 3.0),
-             ("serve.exec", 2.0, 3.0), ("serve.respond", 3.0, 4.0)]
-    gaps = [(1.0, 1.5), (2.2, 2.4), (3.5, 3.7), (5.0, 6.0), (11.0, 12.0)]
+             ("serve.exec", 2.0, 3.0), ("serve.respond", 3.0, 4.0),
+             # one call's worker spans inside the above
+             ("serve.fill", 0.5, 1.0), ("serve.exec.dispatch", 2.0, 2.1),
+             ("serve.exec.wait", 2.1, 3.0), ("serve.head", 3.0, 3.2),
+             ("serve.fulfil", 3.2, 3.4)]
+    gaps = [(0.6, 0.8), (1.0, 1.5), (2.0, 2.06), (2.2, 2.4), (3.05, 3.1),
+            (3.25, 3.3), (3.5, 3.7), (5.0, 6.0), (11.0, 12.0)]
     named = devtrace.name_gaps(gaps, spans, harness.GAP_PRIORITY)
-    assert named == pytest.approx({"serve.batch": 0.5, "serve.exec": 0.2,
-                                   "serve.respond": 0.2, "serve.queue": 1.0,
-                                   devtrace.NO_SPAN: 1.0})
+    assert named == pytest.approx({
+        "serve.fill": 0.2, "serve.batch": 0.5, "serve.exec.dispatch": 0.06,
+        "serve.exec.wait": 0.2, "serve.head": 0.05, "serve.fulfil": 0.05,
+        "serve.respond": 0.2, "serve.queue": 1.0, devtrace.NO_SPAN: 1.0})
+    assert harness.GAP_PRIORITY[:5] == (
+        "serve.exec.wait", "serve.exec.dispatch", "serve.head",
+        "serve.fulfil", "serve.fill")
+
+
+# -- a second backbone, added as new files only --------------------------------
+TWIN = "resnet9twin"
+
+
+def test_a_config_naming_no_recipe_fails_in_set_up():
+    cell = tiny("resnet9-w6a4-int.cams128")
+    cell.cfg["model"] = "no-such-backbone"
+    with pytest.raises(ValueError) as err:
+        harness.run_cell(cell, 2**33 + 23, 0.5, False, time.perf_counter())
+    msg = str(err.value)
+    assert "'resnet9-w6a4-int'" in msg and "'no-such-backbone'" in msg
+    assert "'resnet9'" in msg                 # the recipes registered
+
+
+def test_a_second_backbone_added_as_files_is_served_and_counted(tmp_path):
+    """A recipe registered under a new name (resnet9's hooks and exporter),
+    a reference file of that name whose ``macs_per_frame`` counts twice
+    resnet9's, and a configuration and cell of it, each a new file or entry:
+    the cell is served through that recipe, answers correctly, and its
+    ``mfu.cams128`` comes from its own file's count."""
+    from repro.core import recipes
+
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    configs = root / "bench" / "configs"
+    (configs / f"{TWIN}.py").write_text(
+        (configs / "resnet9.py").read_text()
+        + "\n\ndef macs_per_frame(cfg):\n"
+        "    return 2 * sum(m * k * n for _, m, k, n in "
+        "weight_matmuls(cfg))\n")
+    cfg = json.loads((configs / "resnet9-w6a4-int.json").read_text())
+    cfg["model"] = TWIN
+    (configs / "twin-w6a4-int.json").write_text(json.dumps(cfg))
+    bench = harness.load_benchmark()
+    bench["configs"].append({"name": "twin-w6a4-int", "source": "x",
+                             "file": "bench/configs/twin-w6a4-int.json",
+                             "reduced": [], "why": "test"})
+    name = "twin-w6a4-int.cams128"
+    bench["workloads"].append({"name": name, "config": "twin-w6a4-int",
+                               "traffic": "cams128", "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("frames_per_s", "mfu.cams128"):
+            m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    base = recipes.recipe("resnet9")
+    recipes.register_recipe(
+        TWIN, base.passes, exporter=base.exporter,
+        init_params=base.init_params, feature_dim=base.feature_dim,
+        forward=base.forward, quant_layers=base.quant_layers)
+    served = []
+    try:
+        cell = tiny(name, root=str(root))
+        out = harness.run_cell(
+            cell, 2**33 + 29, 0.5, False, time.perf_counter(),
+            fault=lambda s: served.append(s.recorder.deployed_model))
+    finally:
+        del recipes._RECIPES[TWIN]
+    assert out.result["correct"] is True and out.result["failed"] == 0
+    assert [dm.recipe_name for dm in served] == [TWIN]
+    assert "mfu.cams128" in {m["name"] for m in cell.per_layer}
+
+    fps = out.result["metrics"]["frames_per_s"]["value"]
+    assert fps > 0
+    base_cell = tiny("resnet9-w6a4-int.cams128")
+
+    def mfu(c):
+        run = harness.TraceRun(c, [], 0.0, 0.5, devtrace.DeviceTrace({}),
+                               "TPU v5 lite", {"frames_per_s": fps})
+        return harness.reader("mfu.cams128", c.root)(run)
+    assert mfu(cell) == 2 * mfu(base_cell) > 0

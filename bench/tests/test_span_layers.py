@@ -168,3 +168,47 @@ def test_worker_spans_cover_the_recorded_chip_window():
             reach = e
     assert covered / (t1 - t0) >= 0.95
 
+
+
+# What every per-layer reader of both cells returned on the two recorded
+# windows, at 1,000 frames/s, before the model's work counts moved from
+# ``work.py`` into its reference file: how a reader finds its counts may
+# change, what it reads may not.
+RECORDED = {
+    "": {"batch_rows_mean.cams128": 64.0,
+         "exec_p50_ms.cams128": 28.286667999992687,
+         "idle_share.cams128": 58.24032766671665,
+         "fill_p50_ms.cams128": None, "dispatch_p50_ms.cams128": None,
+         "copyback_lag_p50_ms.cams128": None, "head_p50_ms.cams128": None,
+         "fulfil_p50_ms.cams128": None},
+    "spans": {"batch_rows_mean.cams128": 64.0,
+              "exec_p50_ms.cams128": 34.96390800000171,
+              "idle_share.cams128": 38.37952833326159,
+              "fill_p50_ms.cams128": 0.13586000000032072,
+              "dispatch_p50_ms.cams128": 0.40305000000273594,
+              "copyback_lag_p50_ms.cams128": 2.4447180000066737,
+              "head_p50_ms.cams128": 1.8203790000015374,
+              "fulfil_p50_ms.cams128": 1.2638500000008435},
+}
+RECORDED_BY_CELL = {
+    ("", "resnet9-w6a4-int.cams128"): {
+        "mvau_int_roofline": 11.310763090104729,
+        "mfu.cams128": 0.3860120427480916},
+    ("spans", "resnet9-w6a4-int.cams128"): {
+        "mvau_int_roofline": 11.310784355987973,
+        "mfu.cams128": 0.3860120427480916},
+    ("", "resnet9-w6a4-f32.cams128"): {"mfu.cams128": 0.7700646335025381},
+    ("spans", "resnet9-w6a4-f32.cams128"): {"mfu.cams128": 0.7700646335025381},
+}
+
+
+@pytest.mark.parametrize("sub,cell_name", sorted(RECORDED_BY_CELL),
+                         ids=lambda v: v or "window")
+def test_readers_read_as_recorded_on_both_windows(sub, cell_name):
+    meta, dt, _ = _recorded(sub)
+    cell = harness.resolve(harness.load_benchmark(), cell_name)
+    run = harness.TraceRun(cell, meta["spans"], meta["t0"], meta["t_end"], dt,
+                           meta["kind"], {"frames_per_s": 1000.0})
+    want = dict(RECORDED[sub], **RECORDED_BY_CELL[sub, cell_name])
+    assert {m["name"] for m in cell.per_layer} == set(want)
+    assert {n: _read(n, run) for n in want} == want
