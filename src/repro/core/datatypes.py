@@ -271,13 +271,15 @@ def _rule_embed(node, in_specs, g):
     return in_specs[0]
 
 
-@register_datatype_rule("rmsnorm", "silu", "gelu", "attn_decode",
-                        "attn_prefill")
+@register_datatype_rule("rmsnorm", "layernorm", "silu", "gelu",
+                        "attn_decode", "attn_prefill", "attn_full",
+                        "vit_tokens", "cls_readout")
 def _rule_float_transformer(node, in_specs, g):
-    """Normalization / smooth activations / softmax attention: genuinely
-    real-valued ops — the decode workload keeps them floating point and
-    re-enters the integer domain at the next activation quantizer (which
-    the lowering streamlines to a single ``quantize``)."""
+    """Normalization / smooth activations / softmax attention, and the ViT's
+    token assembly and read-out on the float residual stream: genuinely
+    real-valued ops — transformers keep them floating point and re-enter
+    the integer domain at the next activation quantizer (which the lowering
+    streamlines to a single ``quantize``)."""
     return None
 
 

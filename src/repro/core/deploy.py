@@ -469,6 +469,43 @@ class DeployedModel:
         return head + "\n" + self.trace.report()
 
 
+def _check_attention_kernels(graph: Graph, feeds: Dict[str, Any],
+                             interpret: Optional[bool], rtol: float,
+                             atol: float) -> None:
+    """Golden check of every ``attn_full`` node: the Pallas kernel (compiled
+    on a TPU, interpreted elsewhere) against the interpreter's oracle, on
+    the inputs the interpreter fed that node.
+
+    The kernel is float32 at full precision in another order of operations,
+    so it agrees with the oracle to rounding, not bit for bit; where the
+    next activation quantizer meets a value at a rounding boundary, that
+    difference flips a code by one step, and the flip travels on.  So each
+    node is compared alone, never the codes or features downstream of it,
+    within ``atol + rtol * max|oracle|`` (the tolerance scales with the
+    node's largest output, since attention's rounding does).  Graphs
+    without attention are not touched."""
+    from repro.core.graph import execute
+    from repro.kernels import ops as kops
+
+    nodes = [n for n in graph.nodes if n.op == "attn_full"]
+    if not nodes:
+        return
+    probe = graph.copy()
+    probe.outputs = [n.inputs[0] for n in nodes] + [n.outputs[0] for n in nodes]
+    vals = execute(probe, feeds)
+    for node, qkv, want in zip(nodes, vals, vals[len(nodes):]):
+        got = np.asarray(kops.attn_full(qkv, node.attrs["heads"],
+                                        interpret=interpret))
+        want = np.asarray(want)
+        gap = float(np.max(np.abs(got - want)))
+        bound = atol + rtol * float(np.max(np.abs(want)))
+        if not gap <= bound:
+            raise GraphBuildError(
+                f"attention kernel of '{node.outputs[0]}' in graph "
+                f"'{graph.name}' departs from its oracle by {gap:.3g} "
+                f"(bound {bound:.3g})")
+
+
 def compile(graph_or_model: Any, qcfg: Any = None, *,
             recipe: Union[str, R.BuildRecipe],
             datapath: str = "f32",
@@ -505,7 +542,9 @@ def compile(graph_or_model: Any, qcfg: Any = None, *,
         (the differential-testing baseline).  Ignored for ``"f32"``.
       sample_input: optional golden input for FINN-style per-pass IO
         verification (single-input graphs; use ``verify_feeds`` otherwise) —
-        covers the integer lowering stage too.
+        covers the integer lowering stage too, and then checks each float
+        attention kernel against the interpreter's oracle
+        (:func:`_check_attention_kernels`).
       interpret: force Pallas interpret mode (default: auto — interpreted
         off-TPU, compiled on TPU).
       tracer: optional :class:`repro.obs.Tracer` for compiler telemetry
@@ -543,6 +582,8 @@ def compile(graph_or_model: Any, qcfg: Any = None, *,
     result = PassManager(rtol=rtol, atol=atol, tracer=tracer).run(
         graph, passes, verify_feeds=verify_feeds)
     hw = result.graph
+    if verify_feeds is not None:
+        _check_attention_kernels(hw, verify_feeds, interpret, rtol, atol)
     from repro.core.passes import resolve_pass
 
     return DeployedModel(

@@ -19,6 +19,10 @@ Ops (all the paper's ResNet-9 needs, plus the fused HW ops):
 ``mul`` / ``add``  scalar/elementwise affine (scales get folded by passes)
 ``maxpool``        2×2 window max
 ``mvau``           fused matmul+multithreshold — executed by the Pallas kernel
+``layernorm``      LayerNorm with gain and bias (ViT; float between quantizers)
+``vit_tokens``     class token + patch embeddings + learned positions
+``attn_full``      bidirectional multi-head attention over packed q|k|v
+``cls_readout``    the class token of each sequence
 =================  ==========================================================
 
 Tensors flow in a named environment; layouts are tracked as node attrs so the
@@ -303,11 +307,18 @@ def im2col(x: jax.Array, k: int = 3, stride: int = 1,
     The k·k windows are static strided ``lax.slice``s of the padded input,
     joined on the channel axis. Index-array gathers (and jnp's strided
     ``x[a:b:s]``, which lowers to a gather) become ``while`` loops of dynamic
-    slices on the TPU; slices and a concatenate stay plain fusions."""
+    slices on the TPU; slices and a concatenate stay plain fusions.
+
+    Windows that tile the input (``k == stride``, no padding: a ViT's patch
+    embedding) are a reshape and a transpose instead of k·k slices."""
     n, h, w, c = x.shape
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
     oh = (h + 2 * pad - k) // stride + 1
     ow = (w + 2 * pad - k) // stride + 1
+    if k == stride and pad == 0:
+        x = jax.lax.slice(x, (0, 0, 0, 0), (n, oh * k, ow * k, c))
+        x = x.reshape(n, oh, k, ow, k, c).transpose(0, 1, 3, 2, 4, 5)
+        return x.reshape(n, oh, ow, k * k * c)
+    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
     return jnp.concatenate(
         [jax.lax.slice(xp, (0, i, j, 0),
                        (n, i + (oh - 1) * stride + 1,
@@ -456,6 +467,33 @@ def _ex_attn_prefill(node: Node, q, k, v):
     return ref.attn_prefill(q, k, v, node.attrs["heads"])
 
 
+# -- vision-transformer ops (models.vit export) -------------------------------
+def layernorm(x: jax.Array, g: jax.Array, b: jax.Array,
+              eps: float) -> jax.Array:
+    """LayerNorm over the trailing axis in float32, with gain and bias; the
+    QAT model calls it too."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return xc * jax.lax.rsqrt(var + eps) * g + b
+
+
+def vit_tokens(x: jax.Array, cls: jax.Array, pos: jax.Array) -> jax.Array:
+    """(N, GH, GW, D) patch embeddings -> (N, 1 + GH·GW, D) tokens: the
+    class token first, then the patches in row-major order, plus the
+    learned positions (1 + GH·GW, D)."""
+    n, d = x.shape[0], x.shape[-1]
+    patches = x.reshape(n, -1, d)
+    cls = jnp.broadcast_to(cls.astype(patches.dtype), (n, 1, d))
+    return jnp.concatenate([cls, patches], axis=1) + pos
+
+
+def _ex_attn_full(node: Node, qkv):
+    from repro.kernels import ref
+
+    return ref.attn_full(qkv, node.attrs["heads"])
+
+
 _EXECUTORS: Dict[str, Callable[..., jax.Array]] = {
     "im2col": _ex_im2col,
     "matmul": _ex_matmul,
@@ -478,9 +516,14 @@ _EXECUTORS: Dict[str, Callable[..., jax.Array]] = {
     "embed": _ex_embed,
     "rmsnorm": _ex_rmsnorm,
     "silu": lambda node, x: jax.nn.silu(x),
-    "gelu": lambda node, x: jax.nn.gelu(x),
+    "gelu": lambda node, x: jax.nn.gelu(
+        x, approximate=node.attrs.get("approximate", True)),
     "attn_decode": _ex_attn_decode,
     "attn_prefill": _ex_attn_prefill,
+    "layernorm": lambda node, x, g, b: layernorm(x, g, b, node.attrs["eps"]),
+    "vit_tokens": lambda node, x, cls, pos: vit_tokens(x, cls, pos),
+    "cls_readout": lambda node, x: x[:, 0],
+    "attn_full": _ex_attn_full,
 }
 
 

@@ -138,7 +138,8 @@ _RECIPES: Dict[str, BuildRecipe] = {}
 # working without eagerly importing model code; new architectures may call
 # register_lazy_recipe from any package-init hook.
 _LAZY: Dict[str, str] = {"resnet9": "repro.models.resnet9",
-                         "lm-decode": "repro.models.lm"}
+                         "lm-decode": "repro.models.lm",
+                         "vit-s16": "repro.models.vit"}
 
 
 def register_recipe(name: str, passes: Sequence[str], *,

@@ -15,12 +15,14 @@ import jax.numpy as jnp
 
 from repro.core.quant import FixedPointSpec
 from repro.kernels import ref
+from repro.kernels.attention import attn_full_pallas
 from repro.kernels.gap import gap_pallas
 from repro.kernels.mvau import mvau_int_pallas, mvau_pallas
 from repro.kernels.qmatmul import qmatmul_pallas
 
-__all__ = ["mvau", "mvau_int", "qmatmul", "gap", "default_interpret",
-           "graph_op_impls", "kernel_dispatch"]
+__all__ = ["mvau", "mvau_int", "qmatmul", "gap", "attn_full",
+           "default_interpret", "graph_op_impls", "kernel_dispatch",
+           "attn_trace_count"]
 
 
 def default_interpret() -> bool:
@@ -86,6 +88,23 @@ def gap(x: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
     return gap_pallas(x, interpret=interpret)
 
 
+def attn_full(qkv: jax.Array, heads: int,
+              interpret: Optional[bool] = None) -> jax.Array:
+    """Bidirectional multi-head attention over packed q|k|v (B, S, 3D)."""
+    interpret = default_interpret() if interpret is None else interpret
+    return attn_full_pallas(qkv, heads=heads, interpret=interpret)
+
+
+_attn_traces = [0]     # deployed attention nodes traced; see attn_trace_count
+
+
+def attn_trace_count() -> int:
+    """How often this process traced a deployed ``attn_full`` node: once
+    per attention layer of every program compiled (the Pallas kernel on a
+    TPU, its oracle when emulated).  Flat under load means no retrace."""
+    return _attn_traces[0]
+
+
 # ---------------------------------------------------------------------------
 # Graph-node lowering (core.deploy dispatches HW ops onto these kernels)
 # ---------------------------------------------------------------------------
@@ -132,7 +151,7 @@ def kernel_dispatch(node, emulated: bool,
         return "fast-count"
     if op == "requantize":
         return "int-shift"
-    if op in ("mvau", "global_acc_pool"):
+    if op in ("mvau", "global_acc_pool", "attn_full"):
         return "ref-oracle" if emulated else "pallas"
     return "xla"
 
@@ -143,13 +162,14 @@ def graph_op_impls(interpret: Optional[bool] = None):
     ``core.deploy`` overlays these on the interpreter's executor table when
     lowering a streamlined graph to the single jitted ``DeployedModel``
     callable, so the backend decision is made once per compile (not re-read
-    from node attrs on every call).  On TPU the Pallas MVAU/GAP kernels
-    dispatch compiled; off-TPU — where Pallas only *emulates* via interpret
-    mode — nodes lower to the XLA-native oracles from :mod:`ref` instead.
-    Both paths are bit-identical on the fixed-point grid (every operand and
-    partial sum is exactly representable; asserted kernel-vs-oracle in
-    tests/test_kernels.py and compiled-vs-interpreter in
-    tests/test_compile.py).
+    from node attrs on every call).  On TPU the Pallas MVAU/GAP/attention
+    kernels dispatch compiled; off-TPU — where Pallas only *emulates* via
+    interpret mode — nodes lower to the XLA-native oracles from :mod:`ref`
+    instead.  The MVAU/GAP paths are bit-identical on the fixed-point grid
+    (every operand and partial sum is exactly representable; asserted
+    kernel-vs-oracle in tests/test_kernels.py and compiled-vs-interpreter in
+    tests/test_compile.py); float attention agrees with its oracle to float
+    rounding (``repro.compile``'s golden check).
     """
     emulated = default_interpret() if interpret is None else interpret
 
@@ -211,8 +231,15 @@ def graph_op_impls(interpret: Optional[bool] = None):
             x = x.astype(jnp.int32)
         return jnp.sum(x, axis=axes)
 
+    def _attn_full_node(node, qkv):
+        _attn_traces[0] += 1            # trace time only: the body is jitted
+        if emulated:
+            return ref.attn_full(qkv, node.attrs["heads"])
+        return attn_full(qkv, node.attrs["heads"], interpret=False)
+
     return {"mvau": _mvau_node, "mvau_int": _mvau_int_node,
             "matmul_int": _matmul_int_node,
             "multithreshold_int": _multithreshold_int_node,
             "requantize": _requantize_node,
-            "global_acc_pool": _gap_node}
+            "global_acc_pool": _gap_node,
+            "attn_full": _attn_full_node}

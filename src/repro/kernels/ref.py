@@ -228,18 +228,31 @@ def attn_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 
 
 def attn_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
-                 heads: int) -> jax.Array:
-    """Causal self-attention over a whole prompt: q/k/v (B, S, D) f32."""
+                 heads: int, causal: bool = True) -> jax.Array:
+    """Self-attention over a whole sequence: q/k/v (B, S, D) f32; causal
+    for a prompt, bidirectional (every query sees every key) with
+    ``causal=False``."""
     B, S, D = q.shape
     hd = D // heads
     qh = q.astype(jnp.float32).reshape(B, S, heads, hd)
     kh = k.astype(jnp.float32).reshape(B, S, heads, hd)
     vh = v.astype(jnp.float32).reshape(B, S, heads, hd)
     s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh, precision=_F32) / math.sqrt(hd)
-    causal = (jnp.arange(S, dtype=jnp.int32)[None, :]
-              <= jnp.arange(S, dtype=jnp.int32)[:, None])
-    s = jnp.where(causal[None, None, :, :], s, -jnp.inf)
+    if causal:
+        seen = (jnp.arange(S, dtype=jnp.int32)[None, :]
+                <= jnp.arange(S, dtype=jnp.int32)[:, None])
+        s = jnp.where(seen[None, None, :, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", w, vh,
                      precision=_F32).reshape(B, S, D)
     return out.astype(q.dtype)
+
+
+def attn_full(qkv: jax.Array, heads: int) -> jax.Array:
+    """Bidirectional multi-head attention (a ViT block's): ``qkv`` (B, S,
+    3D) holds q, k and v side by side, each with its heads in order of
+    ``hd = D / heads`` columns.  The oracle of
+    :func:`repro.kernels.attention.attn_full_pallas`."""
+    d = qkv.shape[-1] // 3
+    return attn_prefill(qkv[..., :d], qkv[..., d:2 * d], qkv[..., 2 * d:],
+                        heads, causal=False)

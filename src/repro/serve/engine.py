@@ -89,6 +89,8 @@ class ServeEngine:
 
     #: the :meth:`trace_counts` key of the NCM head's traces
     HEAD_TRACES = "ncm_head"
+    #: the :meth:`trace_counts` key of deployed attention nodes' traces
+    ATTN_TRACES = "attn_full"
 
     def __init__(self, registry: ArtifactRegistry, *,
                  max_batch: int = 64, max_queue: int = 256,
@@ -203,10 +205,15 @@ class ServeEngine:
     def trace_counts(self) -> Dict[str, Optional[int]]:
         """Backbone traces per artifact, plus the NCM head's traces under
         :attr:`HEAD_TRACES` (process-wide: one jitted head serves every
-        store).  A flat dict across load is the zero-retrace check; the
+        store) and the deployed attention nodes' under :attr:`ATTN_TRACES`
+        (process-wide: one per attention layer of each backbone program
+        traced).  A flat dict across load is the zero-retrace check; the
         head traces once more for each new class count."""
+        from repro.kernels import ops as kops
+
         counts = self.registry.trace_counts()
         counts[self.HEAD_TRACES] = ncm.trace_count()
+        counts[self.ATTN_TRACES] = kops.attn_trace_count()
         return counts
 
     # -- admission ----------------------------------------------------------

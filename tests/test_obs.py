@@ -395,6 +395,7 @@ def _toy13_feats(x):
 
 def test_head_trace_counter_flat_after_warmup_one_per_class_count():
     from repro.fsl import ncm
+    from repro.kernels import ops as kops
 
     reg = ArtifactRegistry()
     reg.register("toy13", _toy13_feats, default=True)
@@ -409,7 +410,8 @@ def test_head_trace_counter_flat_after_warmup_one_per_class_count():
             eng.submit_register(c, frames(2)).result(timeout=30)
         before = ncm.trace_count()
         base = eng.warmup(img=IMG)       # the head at 2 classes, buckets 1-8
-        assert base == {"toy13": None, head: ncm.trace_count()}
+        assert base == {"toy13": None, head: ncm.trace_count(),
+                        ServeEngine.ATTN_TRACES: kops.attn_trace_count()}
         assert base[head] - before == 4
         for n in range(1, 9):            # every bucket, exact and padded
             eng.submit_classify(frames(n)).result(timeout=30)

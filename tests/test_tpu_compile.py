@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.attention import attn_full_pallas
 from repro.kernels.gap import gap_pallas
 from repro.kernels.mvau import mvau_int_pallas, mvau_pallas
 from repro.kernels.qmatmul import qmatmul_pallas
@@ -71,6 +72,10 @@ KERNELS = {
                  [((1024, 27), F32), ((27, 64), F32), ((64, 15), F32)]),
     "mvau-f32-L255": (mvau_pallas,
                       [((8, 256), F32), ((256, 64), F32), ((64, 255), F32)]),
+    # ViT-S/16's attention at a 64-frame bucket: 197 tokens padded to 200,
+    # 6 heads of 64 taken in 128-lane pairs
+    "attn_full-vit-s16": (partial(attn_full_pallas, heads=6),
+                          [((64, 197, 1152), F32)]),
     "gap-int-batch8": (gap_pallas, [((8, 4, 4, 512), I32)]),
     "gap-f32-ragged": (gap_pallas, [((3, 5, 7, 24), F32)]),
     "qmatmul-int4": (partial(qmatmul_pallas, bits=4),
@@ -142,3 +147,31 @@ def test_lm_tiny_decode_step_compiles_for_v5e(one_chip):
               for n in art.dm.input_names]
     compiled = _compile(lower_graph(g, interpret=False), shapes, one_chip)
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_vit_s16_layer_compiles_for_v5e(one_chip):
+    """One ViT-S/16 layer at its published widths on 64x64 frames (17 tokens), int
+    datapath, lowered with the chip's dispatch at a bucket of 8: every
+    linear an int8 dot, the attention the Pallas kernel."""
+    from repro.core.deploy import lower_graph
+    from repro.core.quant import FixedPointSpec, QuantConfig
+    from repro.fsl.pipeline import FSLPipeline
+    from repro.models import vit
+
+    qcfg = QuantConfig(weight=FixedPointSpec(8, 8, True),
+                       act=FixedPointSpec(8, 5, True))
+    params = jax.jit(vit.init_params)(jax.random.PRNGKey(0))
+    params = dict(params, blocks=params["blocks"][:1],
+                  pos=params["pos"][:17])          # one layer, 4x4 patches
+    pipe = FSLPipeline(arch="vit-s16", width=384, qcfg=qcfg,
+                       easy_augment=False)
+    dm = pipe.deploy(params, datapath="int").deployed_model
+    compiled = _compile(lower_graph(dm.graph, interpret=False),
+                        [((8, 64, 64, 3), F32)], one_chip)
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert "while" not in hlo
+    # patch embedding, qkv, projection, fc1, fc2: int32-accumulated dots
+    int_dots = [ln for ln in hlo.splitlines()
+                if "= s32[" in ln and ("convolution(" in ln or " dot(" in ln)]
+    assert len(int_dots) == 5
