@@ -15,25 +15,38 @@ Model, per frame (NHWC, values in [0, 1]):
   patch weight plus its bias; the class token first, then the patches in
   row-major order, plus the learned positions: 197 tokens of 384;
 * 12 pre-norm layers: LayerNorm (eps 1e-6) -> activation grid -> qkv with
-  bias -> bidirectional softmax attention, 6 heads of 64 -> activation
-  grid -> projection with bias -> residual add; LayerNorm -> activation
-  grid -> linear to 1536 with bias -> exact (erf) GELU -> activation grid
-  -> linear to 384 with bias -> residual add;
+  bias -> activation grid -> bidirectional softmax attention, 6 heads of
+  64, on the codes (below) -> activation grid -> projection with bias ->
+  residual add; LayerNorm -> activation grid -> linear to 1536 with bias ->
+  exact (erf) GELU -> activation grid -> linear to 384 with bias ->
+  residual add;
 * the feature: the class token after the final LayerNorm.
 
 Every linear multiplies an activation on the activation grid by a weight on
 the weight grid.  It is computed exactly: the integer codes multiply in
 int32 (a sum of 1536 products of 8-bit codes can pass 2**24, where float32
-would round), and the sum is scaled by the grids' step.  Every gap from
-the program then comes from the float ops: LayerNorm, attention, GELU and
-the biases and residual adds.  LayerNorm (a reciprocal square root) and
-GELU (through ``erfc``) are written in the order of operations that the
-usual float32 implementations use, so that where the program rounds the
-same way, it agrees to the bit and its codes stay put.
+would round), and the sum is scaled by the grids' step.
+
+Attention is integer, as in FQ-ViT and I-ViT.  The scores are the products
+of q and k codes; a score ``n`` grid steps (of ``2**-2f / sqrt(64)``) below
+its row's largest, ``n = z·floor(ln 2 / step) + r``, has the exp term
+``((B - r)^2 + C) >> (z + shift)``: I-BERT's second-order exp (arXiv
+2101.01321), ``exp(p) ~ 0.3585 (p + 1.353)^2 + 0.344`` on (-ln 2, 0], with
+``B`` and ``C`` those constants on the score grid, ``n`` capped where the
+term is 0, and ``shift`` keeping each term below 2**14.  The weights are the
+terms' shares floored onto 2**-16; the output is the weights times the v
+codes, exact, on the grid 2**-(16 + f).
+
+So the only rounding left is in LayerNorm, GELU and the bias and residual
+adds, written in the order of operations that the usual float32
+implementations use: where the program rounds the same way, it agrees to
+the bit and its codes stay put.
 
 Departures from DINO: no ImageNet input normalisation (an affine per
 channel that folds into the patch embedding's weights and bias), frames in
-[0, 1] on the activation grid, and random weights (:func:`make_params`).
+[0, 1] on the activation grid (about 5-bit pixels), one per-tensor
+power-of-two grid per kind, q, k and v on the activation grid and the
+integer softmax above, and random weights (:func:`make_params`).
 
 Work (:func:`weight_matmuls`, :func:`macs_per_frame`, :func:`attn_work`):
 the benchmark's readers count the operations of a served frame from these.
@@ -43,9 +56,8 @@ support features, L2-normalised again; a query's similarity to a class is
 the cosine between its normalised feature and the normalised mean.
 
 The control variant ``"bf16"`` computes the same model one step lower in
-precision: attention's two products with bfloat16 operands (what a TPU's
-``DEFAULT`` precision does to float32) and every LayerNorm's input and
-output rounded to bfloat16.
+precision: the float values between quantizers in bfloat16 (every linear's
+output after its bias, every LayerNorm's input and output, GELU's output).
 """
 
 from __future__ import annotations
@@ -107,9 +119,10 @@ def key_for(seed: int) -> jax.Array:
 
 
 # Each linear's weights are drawn N(0, (gain / sqrt(K))^2): its output then
-# has about gain times its input's root mean square.  qkv at 1.5 makes the
-# attention logits' spread about 2.3, so a query favours a few keys.
-GAIN = {"patch": 1.0, "qkv": 1.5, "proj": 1.0, "fc1": 1.0, "fc2": 1.0}
+# has about gain times its input's root mean square.  qkv at 1.25 makes the
+# attention logits' spread about 1.7, so a query favours a few keys, and
+# puts under 0.5% of the q, k and v codes in saturation.
+GAIN = {"patch": 1.0, "qkv": 1.25, "proj": 1.0, "fc1": 1.0, "fc2": 1.0}
 BIAS_STD = 0.1          # every linear's bias
 TOKEN_STD = 0.5         # the class token and the positions
 LN_GAIN = (0.5, 1.5)    # LayerNorm gains, uniform
@@ -177,7 +190,7 @@ def _bf16(x):
     return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
 
 
-def _linear(h_codes, w, b, wq: Dict, aq: Dict):
+def _linear(h_codes, w, b, wq: Dict, aq: Dict, control=None):
     """Exact product of activation codes and the (K, N) weight's codes,
     accumulated in int32 (the 8-bit codes go in as int8, which the chip
     multiplies exactly), scaled by the grids' step, plus the bias."""
@@ -185,12 +198,13 @@ def _linear(h_codes, w, b, wq: Dict, aq: Dict):
     acc = jnp.matmul(h_codes.astype(jnp.int8), _codes(w, wq).astype(jnp.int8),
                      preferred_element_type=jnp.int32)
     step = 2.0 ** -(aq["frac_bits"] + wq["frac_bits"])
-    return acc.astype(jnp.float32) * step + b.reshape(-1)
+    y = acc.astype(jnp.float32) * step + b.reshape(-1)
+    return _bf16(y) if control == "bf16" else y
 
 
-def _dense(h_codes, p, wq: Dict, aq: Dict):
+def _dense(h_codes, p, wq: Dict, aq: Dict, control=None):
     return _linear(h_codes, p["w"].reshape(p["w"].shape[0], -1), p["b"],
-                   wq, aq)
+                   wq, aq, control)
 
 
 def _layernorm(x, p, control):
@@ -202,22 +216,33 @@ def _layernorm(x, p, control):
     return _bf16(y) if control == "bf16" else y
 
 
-def _attention(qkv, heads: int, control):
-    n, s, d3 = qkv.shape
+# I-BERT's integer exp: exp(p) ~ EXP_A (p + EXP_B)^2 + EXP_C on (-ln 2, 0]
+EXP_A, EXP_B, EXP_C = 0.3585, 1.353, 0.344
+EXP_BITS = 14           # each exp term below 2**EXP_BITS
+WEIGHT_BITS = 16        # attention weights on the grid 2**-WEIGHT_BITS
+
+
+def _attention(qkv_codes, heads: int, aq: Dict):
+    """Integer bidirectional attention of (n, s, 3d) q|k|v codes."""
+    n, s, d3 = qkv_codes.shape
     d = d3 // 3
     hd = d // heads
-    q, k, v = (qkv[..., i * d:(i + 1) * d].reshape(n, s, heads, hd)
-               for i in range(3))
-    if control == "bf16":
-        q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
-    scores = jnp.einsum("nqhe,nkhe->nhqk", q, k,
-                        preferred_element_type=jnp.float32) / math.sqrt(hd)
-    w = jax.nn.softmax(scores, axis=-1)
-    if control == "bf16":
-        w = w.astype(jnp.bfloat16)
-    out = jnp.einsum("nhqk,nkhe->nqhe", w, v,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(n, s, d)
+    q, k, v = (qkv_codes[..., i * d:(i + 1) * d].reshape(n, s, heads, hd)
+               .astype(jnp.float32) for i in range(3))
+    step = 2.0 ** (-2 * aq["frac_bits"]) / math.sqrt(hd)
+    ln2 = math.floor(math.log(2) / step)
+    b = math.floor(EXP_B / step)
+    c = math.floor(EXP_C / (EXP_A * step * step))
+    shift = max(0, (b * b + c).bit_length() - EXP_BITS)
+    # code products: float32 sums of integers below 2**24, exact
+    scores = jnp.einsum("nqhe,nkhe->nhqk", q, k).astype(jnp.int32)
+    below = jnp.minimum(jnp.max(scores, axis=-1, keepdims=True) - scores,
+                        (31 - shift) * ln2)
+    z, r = below // ln2, below % ln2
+    e = ((b - r) * (b - r) + c) >> (z + shift)
+    w = (e << WEIGHT_BITS) // jnp.sum(e, axis=-1, keepdims=True)
+    out = jnp.einsum("nhqk,nkhe->nqhe", w.astype(jnp.float32), v)
+    return out.reshape(n, s, d) * 2.0 ** -(WEIGHT_BITS + aq["frac_bits"])
 
 
 def _gelu(x):
@@ -234,16 +259,19 @@ def _forward(params, x, cfg, control):
     patches = xc.reshape(n, g, p, g, p, 3).transpose(0, 1, 3, 2, 4, 5)
     t = _linear(patches.reshape(n, g * g, p * p * 3),
                 params["patch"]["w"].reshape(-1, s["width"]),
-                params["patch"]["b"], wq, aq)
+                params["patch"]["b"], wq, aq, control)
     cls = jnp.broadcast_to(params["cls"], (n, 1, s["width"]))
     t = jnp.concatenate([cls, t], axis=1) + params["pos"]
     for blk in params["blocks"]:
         h = _codes(_layernorm(t, blk["ln1"], control), aq)
-        a = _attention(_dense(h, blk["qkv"], wq, aq), s["heads"], control)
-        t = t + _dense(_codes(a, aq), blk["proj"], wq, aq)
+        qkv = _codes(_dense(h, blk["qkv"], wq, aq, control), aq)
+        a = _attention(qkv, s["heads"], aq)
+        t = t + _dense(_codes(a, aq), blk["proj"], wq, aq, control)
         h = _codes(_layernorm(t, blk["ln2"], control), aq)
-        u = _gelu(_dense(h, blk["fc1"], wq, aq))
-        t = t + _dense(_codes(u, aq), blk["fc2"], wq, aq)
+        u = _gelu(_dense(h, blk["fc1"], wq, aq, control))
+        if control == "bf16":
+            u = _bf16(u)
+        t = t + _dense(_codes(u, aq), blk["fc2"], wq, aq, control)
     return _layernorm(t[:, 0], params["norm"], control)
 
 

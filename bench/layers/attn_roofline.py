@@ -8,10 +8,11 @@ written in float32, for the real rows only), over the device time of the
 
 Each backbone call (one ``serve.batch`` span) runs one attention kernel per
 layer; the kernels are matched to the call whose span they start in, and
-only calls the trace saw whole (``depth`` kernels) count.  The kernel runs
-float32 at ``HIGHEST`` precision, about six bf16 passes on the MXU, and its
-64-wide heads fill half of the MXU's depth, so it cannot reach the bf16
-peak: the reading stays well under 100%."""
+only calls the trace saw whole (``depth`` kernels) count.  The kernel is
+integer: its MXU passes take bfloat16 codes (the weights in two passes,
+their high and low bytes), its 64-wide heads fill half of the MXU's depth,
+and its softmax runs on the vector unit, so it cannot reach the bf16 peak:
+the reading stays well under 100%."""
 
 KERNEL = "attn_full"
 

@@ -4,8 +4,8 @@
 
 The configuration ``vit-s16-int`` cut to width 64, 2 heads, 2 layers and
 32x32 frames of 8x8 patches, served through the harness as ``run.py``
-serves it: the program answers correctly, the control (bfloat16 attention
-and LayerNorm) fails the comparison, and the reference counts the
+serves it: the program answers correctly, the control (its float values
+between quantizers in bfloat16) fails the comparison, and the reference counts the
 published model's work.
 """
 

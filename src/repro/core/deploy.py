@@ -470,20 +470,11 @@ class DeployedModel:
 
 
 def _check_attention_kernels(graph: Graph, feeds: Dict[str, Any],
-                             interpret: Optional[bool], rtol: float,
-                             atol: float) -> None:
+                             interpret: Optional[bool]) -> None:
     """Golden check of every ``attn_full`` node: the Pallas kernel (compiled
     on a TPU, interpreted elsewhere) against the interpreter's oracle, on
-    the inputs the interpreter fed that node.
-
-    The kernel is float32 at full precision in another order of operations,
-    so it agrees with the oracle to rounding, not bit for bit; where the
-    next activation quantizer meets a value at a rounding boundary, that
-    difference flips a code by one step, and the flip travels on.  So each
-    node is compared alone, never the codes or features downstream of it,
-    within ``atol + rtol * max|oracle|`` (the tolerance scales with the
-    node's largest output, since attention's rounding does).  Graphs
-    without attention are not touched."""
+    the inputs the interpreter fed that node.  The attention is integer, so
+    the two agree bit for bit; graphs without attention are not touched."""
     from repro.core.graph import execute
     from repro.kernels import ops as kops
 
@@ -495,15 +486,14 @@ def _check_attention_kernels(graph: Graph, feeds: Dict[str, Any],
     vals = execute(probe, feeds)
     for node, qkv, want in zip(nodes, vals, vals[len(nodes):]):
         got = np.asarray(kops.attn_full(qkv, node.attrs["heads"],
+                                        node.attrs["frac_bits"],
                                         interpret=interpret))
-        want = np.asarray(want)
-        gap = float(np.max(np.abs(got - want)))
-        bound = atol + rtol * float(np.max(np.abs(want)))
-        if not gap <= bound:
+        diff = int(np.sum(got != np.asarray(want)))
+        if diff:
             raise GraphBuildError(
                 f"attention kernel of '{node.outputs[0]}' in graph "
-                f"'{graph.name}' departs from its oracle by {gap:.3g} "
-                f"(bound {bound:.3g})")
+                f"'{graph.name}' departs from its oracle in {diff} of "
+                f"{got.size} outputs")
 
 
 def compile(graph_or_model: Any, qcfg: Any = None, *,
@@ -542,8 +532,8 @@ def compile(graph_or_model: Any, qcfg: Any = None, *,
         (the differential-testing baseline).  Ignored for ``"f32"``.
       sample_input: optional golden input for FINN-style per-pass IO
         verification (single-input graphs; use ``verify_feeds`` otherwise) —
-        covers the integer lowering stage too, and then checks each float
-        attention kernel against the interpreter's oracle
+        covers the integer lowering stage too, and then checks each
+        attention kernel against the interpreter's oracle, bit for bit
         (:func:`_check_attention_kernels`).
       interpret: force Pallas interpret mode (default: auto — interpreted
         off-TPU, compiled on TPU).
@@ -583,7 +573,7 @@ def compile(graph_or_model: Any, qcfg: Any = None, *,
         graph, passes, verify_feeds=verify_feeds)
     hw = result.graph
     if verify_feeds is not None:
-        _check_attention_kernels(hw, verify_feeds, interpret, rtol, atol)
+        _check_attention_kernels(hw, verify_feeds, interpret)
     from repro.core.passes import resolve_pass
 
     return DeployedModel(

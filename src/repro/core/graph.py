@@ -21,7 +21,7 @@ Ops (all the paper's ResNet-9 needs, plus the fused HW ops):
 ``mvau``           fused matmul+multithreshold — executed by the Pallas kernel
 ``layernorm``      LayerNorm with gain and bias (ViT; float between quantizers)
 ``vit_tokens``     class token + patch embeddings + learned positions
-``attn_full``      bidirectional multi-head attention over packed q|k|v
+``attn_full``      bidirectional integer attention over packed q|k|v codes
 ``cls_readout``    the class token of each sequence
 =================  ==========================================================
 
@@ -491,7 +491,7 @@ def vit_tokens(x: jax.Array, cls: jax.Array, pos: jax.Array) -> jax.Array:
 def _ex_attn_full(node: Node, qkv):
     from repro.kernels import ref
 
-    return ref.attn_full(qkv, node.attrs["heads"])
+    return ref.attn_full(qkv, node.attrs["heads"], node.attrs["frac_bits"])
 
 
 _EXECUTORS: Dict[str, Callable[..., jax.Array]] = {

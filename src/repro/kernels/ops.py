@@ -88,11 +88,13 @@ def gap(x: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
     return gap_pallas(x, interpret=interpret)
 
 
-def attn_full(qkv: jax.Array, heads: int,
+def attn_full(qkv: jax.Array, heads: int, frac_bits: int,
               interpret: Optional[bool] = None) -> jax.Array:
-    """Bidirectional multi-head attention over packed q|k|v (B, S, 3D)."""
+    """Bidirectional multi-head attention over packed q|k|v (B, S, 3D) on
+    the activation grid of ``frac_bits`` fraction bits."""
     interpret = default_interpret() if interpret is None else interpret
-    return attn_full_pallas(qkv, heads=heads, interpret=interpret)
+    return attn_full_pallas(qkv, heads=heads, frac_bits=frac_bits,
+                            interpret=interpret)
 
 
 _attn_traces = [0]     # deployed attention nodes traced; see attn_trace_count
@@ -168,8 +170,8 @@ def graph_op_impls(interpret: Optional[bool] = None):
     instead.  The MVAU/GAP paths are bit-identical on the fixed-point grid
     (every operand and partial sum is exactly representable; asserted
     kernel-vs-oracle in tests/test_kernels.py and compiled-vs-interpreter in
-    tests/test_compile.py); float attention agrees with its oracle to float
-    rounding (``repro.compile``'s golden check).
+    tests/test_compile.py), and so is the integer attention
+    (tests/test_vit.py; ``repro.compile``'s golden check).
     """
     emulated = default_interpret() if interpret is None else interpret
 
@@ -233,9 +235,10 @@ def graph_op_impls(interpret: Optional[bool] = None):
 
     def _attn_full_node(node, qkv):
         _attn_traces[0] += 1            # trace time only: the body is jitted
+        heads, frac = node.attrs["heads"], node.attrs["frac_bits"]
         if emulated:
-            return ref.attn_full(qkv, node.attrs["heads"])
-        return attn_full(qkv, node.attrs["heads"], interpret=False)
+            return ref.attn_full(qkv, heads, frac)
+        return attn_full(qkv, heads, frac, interpret=False)
 
     return {"mvau": _mvau_node, "mvau_int": _mvau_int_node,
             "matmul_int": _matmul_int_node,

@@ -228,31 +228,82 @@ def attn_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
 
 
 def attn_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
-                 heads: int, causal: bool = True) -> jax.Array:
-    """Self-attention over a whole sequence: q/k/v (B, S, D) f32; causal
-    for a prompt, bidirectional (every query sees every key) with
-    ``causal=False``."""
+                 heads: int) -> jax.Array:
+    """Causal self-attention over a whole prompt: q/k/v (B, S, D) f32."""
     B, S, D = q.shape
     hd = D // heads
     qh = q.astype(jnp.float32).reshape(B, S, heads, hd)
     kh = k.astype(jnp.float32).reshape(B, S, heads, hd)
     vh = v.astype(jnp.float32).reshape(B, S, heads, hd)
     s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh, precision=_F32) / math.sqrt(hd)
-    if causal:
-        seen = (jnp.arange(S, dtype=jnp.int32)[None, :]
-                <= jnp.arange(S, dtype=jnp.int32)[:, None])
-        s = jnp.where(seen[None, None, :, :], s, -jnp.inf)
+    causal = (jnp.arange(S, dtype=jnp.int32)[None, :]
+              <= jnp.arange(S, dtype=jnp.int32)[:, None])
+    s = jnp.where(causal[None, None, :, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", w, vh,
                      precision=_F32).reshape(B, S, D)
     return out.astype(q.dtype)
 
 
-def attn_full(qkv: jax.Array, heads: int) -> jax.Array:
-    """Bidirectional multi-head attention (a ViT block's): ``qkv`` (B, S,
-    3D) holds q, k and v side by side, each with its heads in order of
-    ``hd = D / heads`` columns.  The oracle of
+# -- integer bidirectional attention (the ViT's) -------------------------------
+# I-BERT's second-order exp (Kim et al., arXiv 2101.01321): on (-ln 2, 0],
+# exp(p) ~ A (p + B)^2 + C, within 0.31%; exp(p - z ln 2) = exp(p) >> z.
+IEXP_A, IEXP_B, IEXP_C = 0.3585, 1.353, 0.344
+PROB_BITS = 16      # attention weights on the grid 2**-16
+EXP_BITS = 14       # exp terms below 2**14, so e << PROB_BITS fits int32
+
+
+def iexp_consts(frac_bits: int, head_dim: int):
+    """Integer constants of the softmax over scores on the grid
+    ``2**(-2 frac_bits) / sqrt(head_dim)`` (q·k of two activation codes,
+    scaled): ``(q_ln2, q_b, q_c, shift, n_max)``.  A score ``n`` grid steps
+    below its row's largest has the exp term
+    ``((q_b - r)^2 + q_c) >> (z + shift)``, with ``n = z q_ln2 + r``;
+    ``n`` is capped at ``n_max``, where the term is 0."""
+    step = 2.0 ** (-2 * frac_bits) / math.sqrt(head_dim)
+    q_ln2 = math.floor(math.log(2.0) / step)
+    q_b = math.floor(IEXP_B / step)
+    q_c = math.floor(IEXP_C / (IEXP_A * step * step))
+    top = q_b * q_b + q_c
+    if q_ln2 < 1 or top >= 2 ** 31:
+        raise ValueError(f"no int32 softmax for activations of {frac_bits} "
+                         f"fraction bits at head size {head_dim}")
+    shift = max(0, top.bit_length() - EXP_BITS)
+    return q_ln2, q_b, q_c, shift, (31 - shift) * q_ln2
+
+
+def _bf16_dot(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """Product of integer-valued operands that bfloat16 holds exactly, with
+    float32 sums below 2**24: exact, in any order of summation."""
+    return jnp.einsum(spec, a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+
+
+def attn_full(qkv: jax.Array, heads: int, frac_bits: int) -> jax.Array:
+    """Bidirectional multi-head attention on activation codes (a ViT block's).
+
+    ``qkv`` (B, S, 3D) holds q, k and v side by side on the activation grid
+    of ``frac_bits`` fraction bits (8-bit codes), each with its heads in
+    order of ``hd = D / heads`` columns.  Integer throughout: scores are
+    exact code products; the softmax is I-BERT's integer exp
+    (:func:`iexp_consts`) and weights floored onto ``2**-PROB_BITS``; the
+    output is the exact product of weights and v codes, as float32 on the
+    grid ``2**-(PROB_BITS + frac_bits)``.  Every step is exact, so any
+    order of evaluation gives these bits.  The oracle of
     :func:`repro.kernels.attention.attn_full_pallas`."""
-    d = qkv.shape[-1] // 3
-    return attn_prefill(qkv[..., :d], qkv[..., d:2 * d], qkv[..., 2 * d:],
-                        heads, causal=False)
+    b, s, d3 = qkv.shape
+    d = d3 // 3
+    hd = d // heads
+    q_ln2, q_b, q_c, shift, n_max = iexp_consts(frac_bits, hd)
+    codes = jnp.round(qkv.astype(jnp.float32) * 2.0 ** frac_bits)
+    q, k, v = (codes[..., i * d:(i + 1) * d].reshape(b, s, heads, hd)
+               for i in range(3))
+    sc = _bf16_dot("bqhe,bkhe->bhqk", q, k).astype(jnp.int32)
+    n = jnp.minimum(jnp.max(sc, axis=-1, keepdims=True) - sc, n_max)
+    z = n // q_ln2
+    t = q_b - (n - z * q_ln2)
+    e = jax.lax.shift_right_arithmetic(t * t + q_c, z + shift)
+    p = (e << PROB_BITS) // jnp.sum(e, axis=-1, keepdims=True)
+    o = (256.0 * _bf16_dot("bhqk,bkhe->bqhe", p >> 8, v)
+         + _bf16_dot("bhqk,bkhe->bqhe", p & 255, v))
+    return o.reshape(b, s, d) * 2.0 ** -(PROB_BITS + frac_bits)

@@ -11,8 +11,11 @@ add.  The feature is the class token after the final LayerNorm.
 W8A8 on the integer datapath: an activation quantizer sits at the input of
 every linear (after each LayerNorm, after attention, after GELU; the frame
 rides the same grid), so every linear's operands are 8-bit codes and run as
-``matmul_int`` (``int8-dot`` on the chip).  LayerNorm, attention, GELU, the
-biases and the residual stream stay float32 between quantizers.
+``matmul_int`` (``int8-dot`` on the chip).  Attention is integer too, as in
+FQ-ViT and I-ViT: q, k and v are put on the same grid, and the scores, an
+integer softmax (I-BERT's exp) and the weighted sum of v are exact
+(``kernels.ref.attn_full``).  LayerNorm, GELU, the biases and the residual
+stream stay float32 between quantizers.
 
 Two execution forms, numerically identical by construction (the same
 helpers from ``core.graph`` and ``kernels.ref``):
@@ -22,8 +25,8 @@ helpers from ``core.graph`` and ``kernels.ref``):
 2. **Exported dataflow graph** (:func:`export_graph`): linears as ``matmul``
    + bias ``add`` with quantized weight initializers, each activation
    quantizer a canonical-grid ``multithreshold`` that integer lowering
-   streamlines to ``quantize``, attention as one ``attn_full`` node (the
-   Pallas kernel on the chip).
+   streamlines to ``quantize``, attention as one ``attn_full`` node on the
+   quantized q|k|v (the Pallas kernel on the chip).
 
 The structure (width, heads, depth, patch, frame size, MLP width) is read
 from the parameter shapes, so one recipe serves every size: the qkv weight
@@ -162,7 +165,8 @@ def forward(params: Params, x: jax.Array, qcfg: Optional[QuantConfig] = None,
         p = f"l{i}."
         h = layernorm(t, blk["ln1"]["g"], blk["ln1"]["b"], EPS)
         qkv = linear(quant_for(h, p + "qkv"), p + "qkv")
-        a = ref.attn_full(qkv, dm.heads)
+        a = ref.attn_full(quant_for(qkv, p + "attn"), dm.heads,
+                          specs(p + "attn")[1].frac_bits)
         t = t + linear(quant_for(a, p + "proj"), p + "proj")
         h = layernorm(t, blk["ln2"]["g"], blk["ln2"]["b"], EPS)
         u = jax.nn.gelu(linear(quant_for(h, p + "fc1"), p + "fc1"),
@@ -189,9 +193,9 @@ def export_graph(params: Params, qcfg: QuantConfig,
         return nm
 
     def act_quant(src, lname):
-        """FINN activation quantizer at a linear's input: multithreshold
-        over the canonical grid of its activation spec, one table per node
-        (integer lowering rewrites tables in place)."""
+        """FINN activation quantizer at a linear's or the attention's input:
+        multithreshold over the canonical grid of its activation spec, one
+        table per node (integer lowering rewrites tables in place)."""
         aspec = qcfg.layer(lname).act
         out = f"{lname}.aq"
         t = const(out + "_t", thresholds_for(aspec))
@@ -226,9 +230,13 @@ def export_graph(params: Params, qcfg: QuantConfig,
     for i, blk in enumerate(params["blocks"]):
         p = f"l{i}."
         h = norm(t, blk["ln1"], p + "ln1")
-        qkv = linear(act_quant(h, p + "qkv"), p + "qkv")
+        if qcfg.layer(p + "attn").act.total_bits > 8:
+            raise ValueError("the integer attention takes 8-bit codes")
+        qkv = act_quant(linear(act_quant(h, p + "qkv"), p + "qkv"),
+                        p + "attn")
         nodes.append(Node("attn_full", [qkv], [p + "attn"],
-                          {"heads": dm.heads}))
+                          {"heads": dm.heads,
+                           "frac_bits": qcfg.layer(p + "attn").act.frac_bits}))
         o = linear(act_quant(p + "attn", p + "proj"), p + "proj")
         nodes.append(Node("add", [t, o], [p + "r1"]))
         h = norm(p + "r1", blk["ln2"], p + "ln2")
@@ -259,7 +267,7 @@ def _register_recipe():
         [],   # datatype passes ride in via repro.compile(datapath="int"); no
               # CNN streamlining, and float attention is not HW-mappable
         description=("DINO ViT-S/16 few-shot backbone: datatype inference + "
-                     "integer lowering, attention on the Pallas kernel"),
+                     "integer lowering, integer attention on the Pallas kernel"),
         exporter=_export_for_compile,
         init_params=init_params,
         feature_dim=feature_dim,

@@ -73,8 +73,8 @@ KERNELS = {
     "mvau-f32-L255": (mvau_pallas,
                       [((8, 256), F32), ((256, 64), F32), ((64, 255), F32)]),
     # ViT-S/16's attention at a 64-frame bucket: 197 tokens padded to 200,
-    # 6 heads of 64 taken in 128-lane pairs
-    "attn_full-vit-s16": (partial(attn_full_pallas, heads=6),
+    # 6 heads of 64 taken in 128-lane pairs, codes of 5 fraction bits
+    "attn_full-vit-s16": (partial(attn_full_pallas, heads=6, frac_bits=5),
                           [((64, 197, 1152), F32)]),
     "gap-int-batch8": (gap_pallas, [((8, 4, 4, 512), I32)]),
     "gap-f32-ragged": (gap_pallas, [((3, 5, 7, 24), F32)]),
