@@ -18,9 +18,9 @@ Model, per frame (NHWC, values in [0, 1]):
   bias -> activation grid -> bidirectional softmax attention, 6 heads of
   64, on the codes (below) -> activation grid -> projection with bias ->
   residual add; LayerNorm -> activation grid -> linear to 1536 with bias ->
-  exact (erf) GELU -> activation grid -> linear to 384 with bias ->
-  residual add;
-* the feature: the class token after the final LayerNorm.
+  GELU -> activation grid -> linear to 384 with bias -> residual add;
+* the feature: the class token after the final LayerNorm, on the grid of
+  the linears' sums (2**-13).
 
 Every linear multiplies an activation on the activation grid by a weight on
 the weight grid.  It is computed exactly: the integer codes multiply in
@@ -37,16 +37,22 @@ term is 0, and ``shift`` keeping each term below 2**14.  The weights are the
 terms' shares floored onto 2**-16; the output is the weights times the v
 codes, exact, on the grid 2**-(16 + f).
 
-So the only rounding left is in LayerNorm, GELU and the bias and residual
-adds, written in the order of operations that the usual float32
-implementations use: where the program rounds the same way, it agrees to
-the bit and its codes stay put.
+LayerNorm and GELU are integer too, as in I-BERT (:func:`_layernorm`,
+:func:`_gelu`): each puts its float32 input on a grid (LayerNorm on the
+linears' grid 2**-13, GELU on 2**-8) and returns the next quantizer's grid
+(the final LayerNorm, 2**-13).  LayerNorm scales the deviations from the
+mean to 12 bits, takes an integer square root of their mean square and
+divides; GELU is x times I-BERT's second-order fit of the normal CDF.
+
+So the only float rounding left is one operation per value: a bias added to
+a linear's exact sum, and a residual add.  Any program that computes the
+same model rounds the same way and agrees to the bit.
 
 Departures from DINO: no ImageNet input normalisation (an affine per
 channel that folds into the patch embedding's weights and bias), frames in
 [0, 1] on the activation grid (about 5-bit pixels), one per-tensor
-power-of-two grid per kind, q, k and v on the activation grid and the
-integer softmax above, and random weights (:func:`make_params`).
+power-of-two grid per kind, q, k and v on the activation grid, the integer
+softmax, LayerNorm and GELU above, and random weights (:func:`make_params`).
 
 Work (:func:`weight_matmuls`, :func:`macs_per_frame`, :func:`attn_work`):
 the benchmark's readers count the operations of a served frame from these.
@@ -57,7 +63,7 @@ the cosine between its normalised feature and the normalised mean.
 
 The control variant ``"bf16"`` computes the same model one step lower in
 precision: the float values between quantizers in bfloat16 (every linear's
-output after its bias, every LayerNorm's input and output, GELU's output).
+output after its bias, and the residual stream after every add).
 """
 
 from __future__ import annotations
@@ -121,7 +127,7 @@ def key_for(seed: int) -> jax.Array:
 # Each linear's weights are drawn N(0, (gain / sqrt(K))^2): its output then
 # has about gain times its input's root mean square.  qkv at 1.25 makes the
 # attention logits' spread about 1.7, so a query favours a few keys, and
-# puts under 0.5% of the q, k and v codes in saturation.
+# puts about 0.5% of the q, k and v codes in saturation.
 GAIN = {"patch": 1.0, "qkv": 1.25, "proj": 1.0, "fc1": 1.0, "fc2": 1.0}
 BIAS_STD = 0.1          # every linear's bias
 TOKEN_STD = 0.5         # the class token and the positions
@@ -190,6 +196,12 @@ def _bf16(x):
     return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
 
 
+def _round(x, control):
+    """A float value between quantizers: float32, or bfloat16 in the
+    control."""
+    return _bf16(x) if control == "bf16" else x
+
+
 def _linear(h_codes, w, b, wq: Dict, aq: Dict, control=None):
     """Exact product of activation codes and the (K, N) weight's codes,
     accumulated in int32 (the 8-bit codes go in as int8, which the chip
@@ -198,8 +210,7 @@ def _linear(h_codes, w, b, wq: Dict, aq: Dict, control=None):
     acc = jnp.matmul(h_codes.astype(jnp.int8), _codes(w, wq).astype(jnp.int8),
                      preferred_element_type=jnp.int32)
     step = 2.0 ** -(aq["frac_bits"] + wq["frac_bits"])
-    y = acc.astype(jnp.float32) * step + b.reshape(-1)
-    return _bf16(y) if control == "bf16" else y
+    return _round(acc.astype(jnp.float32) * step + b.reshape(-1), control)
 
 
 def _dense(h_codes, p, wq: Dict, aq: Dict, control=None):
@@ -207,13 +218,86 @@ def _dense(h_codes, p, wq: Dict, aq: Dict, control=None):
                    wq, aq, control)
 
 
-def _layernorm(x, p, control):
-    if control == "bf16":
-        x = _bf16(x)
-    xc = x - jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    y = xc * jax.lax.rsqrt(var + EPS) * p["g"] + p["b"]
-    return _bf16(y) if control == "bf16" else y
+# LayerNorm and GELU in integer arithmetic, as in I-BERT (arXiv 2101.01321)
+LN_IN_BITS = 23         # LayerNorm input codes within +-2**23
+LN_SQ_BITS = 11         # deviations scaled to at most 2**11 before squaring
+LN_GAIN_FRAC = 10       # gains on the grid 2**-10 (codes within +-2**12)
+LN_SIGMA_FRAC = 4       # the standard deviation to 1/16 of a unit
+GELU_IN_FRAC = 8        # GELU's input on the grid 2**-8 (codes within +-2**15)
+GELU_PHI_FRAC = 10      # the normal CDF on the grid 2**-10
+IGELU_A, IGELU_B = 0.2888, 1.769
+
+
+def _int_codes(x, frac: int, bits: int):
+    """Round half to even onto the grid 2**-frac, within +-2**bits."""
+    q = jnp.round(x * 2.0 ** frac)
+    return jnp.clip(q, -(2 ** bits), 2 ** bits - 1).astype(jnp.int32)
+
+
+def _sqrt_floor(v):
+    """floor(sqrt(v)) for int32 v >= 1: Newton from 46341 >= sqrt(2**31),
+    each step min(x, (x + v // x) // 2), 20 steps."""
+    x = jnp.full_like(v, 46341)
+    for _ in range(20):
+        x = jnp.minimum(x, (x + v // x) // 2)
+    return x
+
+
+def _layernorm(x, p, in_frac: int, out_frac: int):
+    """Integer LayerNorm of the rows of x (float32) onto 2**-out_frac.
+
+    c: the codes of x on 2**-in_frac.  d = c - round(mean c) (half up).  e =
+    d / 2**k rounded half up, k the least whole number with max |d| <
+    2**(11 + k).  var = floor(sum e^2 / n).  sigma = floor(sqrt(var * 2**8
+    + max(floor(eps * 2**(2 in_frac + 8) / 4**k), 1))), the standard
+    deviation of e in sixteenths.  The output code is e * g / sigma (g's
+    code on 2**-10) on 2**-out_frac, rounded half up, plus b's code on
+    2**-out_frac."""
+    n = x.shape[-1]
+    c = _int_codes(x, in_frac, LN_IN_BITS)
+    total = jnp.sum(c, axis=-1, keepdims=True)
+    d = c - jnp.floor_divide(total + n // 2, n)
+    big = jnp.max(jnp.abs(d), axis=-1, keepdims=True)
+    k = jnp.zeros_like(big)
+    for j in range(LN_SQ_BITS, LN_IN_BITS + 2):
+        k = k + (big >= 2 ** j).astype(jnp.int32)
+    half = jnp.where(k > 0, jnp.left_shift(1, jnp.maximum(k - 1, 0)), 0)
+    e = jnp.right_shift(d + half, k)
+    var = jnp.floor_divide(jnp.sum(e * e, axis=-1, keepdims=True), n)
+    eps = jnp.right_shift(round(EPS * 2.0 ** (2 * in_frac + 2 * LN_SIGMA_FRAC)),
+                          2 * k)
+    sigma = _sqrt_floor(var * 2 ** (2 * LN_SIGMA_FRAC) + jnp.maximum(eps, 1))
+    num = e * _int_codes(p["g"], LN_GAIN_FRAC, 12)
+    # twice the quotient num * 2**(out_frac + 4 - 10) / sigma, floored, in
+    # two steps so that no product passes 2**31
+    s = out_frac + LN_SIGMA_FRAC - LN_GAIN_FRAC + 1
+    if s > 0:
+        whole = jnp.floor_divide(num, sigma)
+        rest = num - whole * sigma
+        twice = whole * 2 ** s + jnp.floor_divide(rest * 2 ** s, sigma)
+    else:
+        twice = jnp.floor_divide(num, sigma * 2 ** -s)
+    code = jnp.floor_divide(twice + 1, 2) + _int_codes(p["b"], out_frac, 20)
+    return code.astype(jnp.float32) / 2.0 ** out_frac
+
+
+def _gelu(x, out_frac: int):
+    """I-BERT's integer GELU onto 2**-out_frac: x Phi(x), Phi(x) = (1 +
+    erf(x / sqrt 2)) / 2, with erf(u) ~ sign(u) (1 - 0.2888 (min(|u|,
+    1.769) - 1.769)^2).  On codes q of x on 2**-8: t = min(|q|, qb) - qb,
+    qb the code of 1.769 sqrt 2; Phi's tail 0.2888 t^2 / 2**18 on 2**-10
+    (the constant on 2**-12), rounded half up; Phi = 1 - tail for q > 0,
+    else tail; the product q Phi rounded half up onto 2**-out_frac."""
+    q = _int_codes(x, GELU_IN_FRAC, 15)
+    qb = round(IGELU_B * math.sqrt(2.0) * 2 ** GELU_IN_FRAC)
+    t = jnp.minimum(jnp.abs(q), qb) - qb
+    s = 2 * GELU_IN_FRAC + 14 - GELU_PHI_FRAC
+    tail = jnp.floor_divide(t * t * round(IGELU_A * 2 ** 12) + 2 ** (s - 1),
+                            2 ** s)
+    phi = jnp.where(q > 0, 2 ** GELU_PHI_FRAC - tail, tail)
+    s = GELU_IN_FRAC + GELU_PHI_FRAC - out_frac
+    code = jnp.floor_divide(q * phi + 2 ** (s - 1), 2 ** s)
+    return code.astype(jnp.float32) / 2.0 ** out_frac
 
 
 # I-BERT's integer exp: exp(p) ~ EXP_A (p + EXP_B)^2 + EXP_C on (-ln 2, 0]
@@ -245,14 +329,10 @@ def _attention(qkv_codes, heads: int, aq: Dict):
     return out.reshape(n, s, d) * 2.0 ** -(WEIGHT_BITS + aq["frac_bits"])
 
 
-def _gelu(x):
-    """x·Φ(x), Φ the standard normal distribution function."""
-    return 0.5 * x * jax.scipy.special.erfc(-x * np.float32(math.sqrt(0.5)))
-
-
 def _forward(params, x, cfg, control):
     s = shape(cfg)
     wq, aq = cfg["quant"]["weight"], cfg["quant"]["act"]
+    rf = wq["frac_bits"] + aq["frac_bits"]      # the residual's grid
     n, p = x.shape[0], s["patch"]
     g = s["img"] // p
     xc = _codes(x, aq)
@@ -261,18 +341,18 @@ def _forward(params, x, cfg, control):
                 params["patch"]["w"].reshape(-1, s["width"]),
                 params["patch"]["b"], wq, aq, control)
     cls = jnp.broadcast_to(params["cls"], (n, 1, s["width"]))
-    t = jnp.concatenate([cls, t], axis=1) + params["pos"]
+    t = _round(jnp.concatenate([cls, t], axis=1) + params["pos"], control)
     for blk in params["blocks"]:
-        h = _codes(_layernorm(t, blk["ln1"], control), aq)
+        h = _codes(_layernorm(t, blk["ln1"], rf, aq["frac_bits"]), aq)
         qkv = _codes(_dense(h, blk["qkv"], wq, aq, control), aq)
         a = _attention(qkv, s["heads"], aq)
-        t = t + _dense(_codes(a, aq), blk["proj"], wq, aq, control)
-        h = _codes(_layernorm(t, blk["ln2"], control), aq)
-        u = _gelu(_dense(h, blk["fc1"], wq, aq, control))
-        if control == "bf16":
-            u = _bf16(u)
-        t = t + _dense(_codes(u, aq), blk["fc2"], wq, aq, control)
-    return _layernorm(t[:, 0], params["norm"], control)
+        t = _round(t + _dense(_codes(a, aq), blk["proj"], wq, aq, control),
+                   control)
+        h = _codes(_layernorm(t, blk["ln2"], rf, aq["frac_bits"]), aq)
+        u = _gelu(_dense(h, blk["fc1"], wq, aq, control), aq["frac_bits"])
+        t = _round(t + _dense(_codes(u, aq), blk["fc2"], wq, aq, control),
+                   control)
+    return _layernorm(t[:, 0], params["norm"], rf, rf)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg_key", "control"))

@@ -271,15 +271,18 @@ def _rule_embed(node, in_specs, g):
     return in_specs[0]
 
 
-@register_datatype_rule("rmsnorm", "layernorm", "silu", "gelu",
+@register_datatype_rule("rmsnorm", "layernorm", "silu", "gelu", "igelu",
                         "attn_decode", "attn_prefill", "attn_full",
                         "vit_tokens", "cls_readout")
 def _rule_float_transformer(node, in_specs, g):
     """Normalization / smooth activations / softmax attention, and the ViT's
-    token assembly and read-out on the float residual stream: genuinely
-    real-valued ops — transformers keep them floating point and re-enter
-    the integer domain at the next activation quantizer (which the lowering
-    streamlines to a single ``quantize``)."""
+    token assembly and read-out on the float residual stream: real-valued
+    tensors — transformers keep them floating point and re-enter the
+    integer domain at the next activation quantizer (which the lowering
+    streamlines to a single ``quantize``).  The ViT's ``layernorm``,
+    ``igelu`` and ``attn_full`` compute in integers inside and return
+    float32 values on a grid; the quantizer after them is what types
+    them."""
     return None
 
 

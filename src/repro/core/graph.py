@@ -19,7 +19,8 @@ Ops (all the paper's ResNet-9 needs, plus the fused HW ops):
 ``mul`` / ``add``  scalar/elementwise affine (scales get folded by passes)
 ``maxpool``        2×2 window max
 ``mvau``           fused matmul+multithreshold — executed by the Pallas kernel
-``layernorm``      LayerNorm with gain and bias (ViT; float between quantizers)
+``layernorm``      integer LayerNorm with gain and bias (ViT, I-BERT's)
+``igelu``          integer GELU (ViT, I-BERT's)
 ``vit_tokens``     class token + patch embeddings + learned positions
 ``attn_full``      bidirectional integer attention over packed q|k|v codes
 ``cls_readout``    the class token of each sequence
@@ -40,6 +41,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
@@ -468,14 +470,87 @@ def _ex_attn_prefill(node: Node, q, k, v):
 
 
 # -- vision-transformer ops (models.vit export) -------------------------------
-def layernorm(x: jax.Array, g: jax.Array, b: jax.Array,
-              eps: float) -> jax.Array:
-    """LayerNorm over the trailing axis in float32, with gain and bias; the
-    QAT model calls it too."""
-    xf = x.astype(jnp.float32)
-    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    return xc * jax.lax.rsqrt(var + eps) * g + b
+# LayerNorm and GELU in integer arithmetic, as in I-BERT (arXiv 2101.01321):
+# the float input is put on a fixed-point grid (one exact rounding) and every
+# later step is int32, so the output has the same bits in any program, however
+# the compiler fuses it or orders a sum.  The QAT model calls these too.
+LN_IN_BITS = 23      # LayerNorm input codes saturate to +-2**23
+LN_SQ_BITS = 11      # deviations scaled to |e| <= 2**11 before squaring
+LN_GAIN_FRAC = 10    # gains on the grid 2**-10, saturated to +-2**12 codes
+LN_SIGMA_FRAC = 4    # the standard deviation with 4 fraction bits
+GELU_IN_BITS = 15    # GELU input codes saturate to +-2**15
+GELU_PHI_FRAC = 10   # the normal CDF on the grid 2**-10
+IGELU_A, IGELU_B = 0.2888, 1.769    # erf(u) ~ 1 - A (min(u, B) - B)^2, u >= 0
+
+
+def _grid_codes(x: jax.Array, frac: int, bits: int) -> jax.Array:
+    """``x`` on the grid ``2**-frac``, round half to even, saturated to
+    ``[-2**bits, 2**bits - 1]``: int32 codes."""
+    q = jnp.round(x.astype(jnp.float32) * np.float32(2.0 ** frac))
+    return jnp.clip(q, -(2 ** bits), 2 ** bits - 1).astype(jnp.int32)
+
+
+def isqrt(v: jax.Array) -> jax.Array:
+    """``floor(sqrt(v))`` of int32 ``v`` in ``[1, 2**31)``: Newton's step
+    from above, which falls to the answer in at most 16 steps and stays."""
+    x = jnp.full_like(v, 46341)             # ceil(sqrt(2**31))
+    for _ in range(20):
+        x = jnp.minimum(x, (x + v // x) >> 1)
+    return x
+
+
+def layernorm(x: jax.Array, g: jax.Array, b: jax.Array, eps: float,
+              in_frac: int, out_frac: int) -> jax.Array:
+    """Integer LayerNorm over the trailing axis (at most 511 wide), with gain
+    and bias.  ``x`` is rounded onto the grid ``2**-in_frac``; its codes less
+    their rounded mean are scaled by a power of two to at most ``2**11``
+    (``e``), whose mean square, plus ``eps``, gives the standard deviation
+    ``sigma`` with 4 fraction bits (integer square root).  The output is
+    ``e / sigma`` times the gain (on ``2**-10``), rounded half up onto the
+    grid ``2**-out_frac``, plus the bias on that grid: float32, exactly on
+    the grid."""
+    n = x.shape[-1]
+    if n >= 512 or out_frac > LN_GAIN_FRAC + 10:
+        raise ValueError(f"no int32 LayerNorm over {n} at {out_frac} bits")
+    c = _grid_codes(x, in_frac, LN_IN_BITS)
+    d = c - (jnp.sum(c, axis=-1, keepdims=True) + n // 2) // n
+    m = jnp.max(jnp.abs(d), axis=-1, keepdims=True)
+    k = sum((m >= 2 ** j).astype(jnp.int32)
+            for j in range(LN_SQ_BITS, LN_IN_BITS + 2))
+    e = (d + ((1 << k) >> 1)) >> k
+    var = jnp.sum(e * e, axis=-1, keepdims=True) // n
+    eps_q = round(eps * 2.0 ** (2 * (in_frac + LN_SIGMA_FRAC)))
+    sigma = isqrt((var << 2 * LN_SIGMA_FRAC)
+                  + jnp.maximum(eps_q >> 2 * k, 1))
+    gq = _grid_codes(g, LN_GAIN_FRAC, 12)
+    num = e * gq
+    # floor(num * 2**sh / sigma), by long division where sh > 0; half up
+    sh = LN_SIGMA_FRAC + out_frac - LN_GAIN_FRAC + 1
+    if sh > 0:
+        q0 = num // sigma
+        t = q0 * (2 ** sh) + ((num - q0 * sigma) << sh) // sigma
+    else:
+        t = num // (sigma * 2 ** -sh)
+    y = ((t + 1) >> 1) + _grid_codes(b, out_frac, 20)
+    return y.astype(jnp.float32) * np.float32(2.0 ** -out_frac)
+
+
+def igelu(x: jax.Array, in_frac: int, out_frac: int) -> jax.Array:
+    """I-BERT's integer GELU: ``x Phi(x)`` with ``Phi(x) = (1 + erf(x /
+    sqrt 2)) / 2`` and erf's second-order fit, on int32 codes of ``x`` on
+    the grid ``2**-in_frac`` (at most 9 bits); ``Phi`` on ``2**-10``; the
+    product rounded half up onto the grid ``2**-out_frac``, as float32."""
+    if not 0 <= in_frac <= 9 or not 0 <= out_frac < in_frac + GELU_PHI_FRAC:
+        raise ValueError(f"no int32 GELU from {in_frac} to {out_frac} bits")
+    q = _grid_codes(x, in_frac, GELU_IN_BITS)
+    qb = round(IGELU_B * math.sqrt(2.0) * 2 ** in_frac)
+    t = jnp.minimum(jnp.abs(q), qb) - qb
+    sh = 2 * in_frac + 14 - GELU_PHI_FRAC
+    tail = (t * t * round(IGELU_A * 2 ** 12) + (1 << (sh - 1))) >> sh
+    phi = jnp.where(q > 0, 2 ** GELU_PHI_FRAC - tail, tail)
+    sh = in_frac + GELU_PHI_FRAC - out_frac
+    y = (q * phi + (1 << (sh - 1))) >> sh
+    return y.astype(jnp.float32) * np.float32(2.0 ** -out_frac)
 
 
 def vit_tokens(x: jax.Array, cls: jax.Array, pos: jax.Array) -> jax.Array:
@@ -520,7 +595,11 @@ _EXECUTORS: Dict[str, Callable[..., jax.Array]] = {
         x, approximate=node.attrs.get("approximate", True)),
     "attn_decode": _ex_attn_decode,
     "attn_prefill": _ex_attn_prefill,
-    "layernorm": lambda node, x, g, b: layernorm(x, g, b, node.attrs["eps"]),
+    "layernorm": lambda node, x, g, b: layernorm(
+        x, g, b, node.attrs["eps"], node.attrs["in_frac"],
+        node.attrs["out_frac"]),
+    "igelu": lambda node, x: igelu(x, node.attrs["in_frac"],
+                                   node.attrs["out_frac"]),
     "vit_tokens": lambda node, x, cls, pos: vit_tokens(x, cls, pos),
     "cls_readout": lambda node, x: x[:, 0],
     "attn_full": _ex_attn_full,

@@ -5,8 +5,8 @@ the backbone of the P>M>F prototype classifier (arXiv 2204.07305): 16x16
 patches of a 224x224 frame, a class token and learned positions (197
 tokens), width 384, 6 heads of 64, 12 pre-norm layers, each LayerNorm (eps
 1e-6) → qkv with bias → bidirectional softmax attention → projection →
-residual add, LayerNorm → MLP of 1536 with exact (erf) GELU → residual
-add.  The feature is the class token after the final LayerNorm.
+residual add, LayerNorm → MLP of 1536 with GELU → residual add.  The
+feature is the class token after the final LayerNorm.
 
 W8A8 on the integer datapath: an activation quantizer sits at the input of
 every linear (after each LayerNorm, after attention, after GELU; the frame
@@ -14,8 +14,12 @@ rides the same grid), so every linear's operands are 8-bit codes and run as
 ``matmul_int`` (``int8-dot`` on the chip).  Attention is integer too, as in
 FQ-ViT and I-ViT: q, k and v are put on the same grid, and the scores, an
 integer softmax (I-BERT's exp) and the weighted sum of v are exact
-(``kernels.ref.attn_full``).  LayerNorm, GELU, the biases and the residual
-stream stay float32 between quantizers.
+(``kernels.ref.attn_full``).  LayerNorm and GELU are integer inside, as in
+I-BERT (``core.graph.layernorm`` and ``igelu``): each rounds its float32
+input onto a grid and returns float32 values on the next quantizer's grid.
+The biases and the residual stream stay float32, where each value is one
+exact or correctly rounded operation, so the features have the same bits
+in any program.
 
 Two execution forms, numerically identical by construction (the same
 helpers from ``core.graph`` and ``kernels.ref``):
@@ -26,7 +30,8 @@ helpers from ``core.graph`` and ``kernels.ref``):
    + bias ``add`` with quantized weight initializers, each activation
    quantizer a canonical-grid ``multithreshold`` that integer lowering
    streamlines to ``quantize``, attention as one ``attn_full`` node on the
-   quantized q|k|v (the Pallas kernel on the chip).
+   quantized q|k|v (the Pallas kernel on the chip), LayerNorm and GELU as
+   ``layernorm`` and ``igelu`` nodes with their grids as attributes.
 
 The structure (width, heads, depth, patch, frame size, MLP width) is read
 from the parameter shapes, so one recipe serves every size: the qkv weight
@@ -43,13 +48,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import Graph, Node, im2col, layernorm, vit_tokens
+from repro.core.graph import (Graph, Node, igelu, im2col, layernorm,
+                              vit_tokens)
 from repro.core.quant import QuantConfig, fake_quant, thresholds_for
 from repro.kernels import ref
 
 Params = Dict[str, Any]
 
 EPS = 1e-6
+GELU_IN_FRAC = 8        # GELU reads its input on the grid 2**-8
 _HIGHEST = jax.lax.Precision.HIGHEST
 LINEARS = ("qkv", "proj", "fc1", "fc2")
 
@@ -136,19 +143,34 @@ def _weight(params: Params, name: str):
     return p["w"].reshape(p["w"].shape[0], -1), p["b"].reshape(-1)
 
 
-def forward(params: Params, x: jax.Array, qcfg: Optional[QuantConfig] = None,
+def res_frac(qcfg: QuantConfig) -> int:
+    """Fraction bits of the grid on which LayerNorm reads the residual
+    stream: the linears' accumulator grid (weight step times activation
+    step) of the top-level specs."""
+    return qcfg.weight.frac_bits + qcfg.act.frac_bits
+
+
+def forward(params: Params, x: jax.Array, qcfg: QuantConfig,
             width: Optional[int] = None) -> jax.Array:
     """x: (B, H, W, 3) NHWC in [0, 1]. Returns (B, width) features: the
     class token after the final LayerNorm.
 
     Each linear resolves its weight grid and its input's activation grid
     through ``qcfg.layer(name)``; the frame rides the top-level activation
-    grid (as the exporter's ``x`` seed and the deploy-time input quant)."""
+    grid (as the exporter's ``x`` seed and the deploy-time input quant).
+    LayerNorm and GELU are integer (``core.graph``): each LayerNorm reads
+    the residual stream on :func:`res_frac`'s grid and writes the next
+    quantizer's grid (the final one, its own input grid), and GELU reads
+    ``GELU_IN_FRAC`` bits."""
+    if qcfg is None:
+        raise ValueError("the ViT's integer LayerNorm and GELU need a "
+                         "QuantConfig for their grids")
     dm = dims(params)
+    rf = res_frac(qcfg)
 
     def specs(name):
-        lq = qcfg.layer(name) if qcfg else None
-        return (lq.weight, lq.act) if lq else (None, None)
+        lq = qcfg.layer(name)
+        return lq.weight, lq.act
 
     def linear(h, name):
         ws, _ = specs(name)
@@ -158,21 +180,25 @@ def forward(params: Params, x: jax.Array, qcfg: Optional[QuantConfig] = None,
     def quant_for(h, name):
         return fake_quant(h, specs(name)[1])
 
-    x = fake_quant(x, qcfg.act if qcfg else None)
+    def norm(t, p, name):
+        out = specs(name)[1].frac_bits if name else rf
+        return layernorm(t, p["g"], p["b"], EPS, rf, out)
+
+    x = fake_quant(x, qcfg.act)
     t = linear(im2col(x, dm.patch, dm.patch, 0), "patch")
     t = vit_tokens(t, params["cls"], params["pos"])
     for i, blk in enumerate(params["blocks"]):
         p = f"l{i}."
-        h = layernorm(t, blk["ln1"]["g"], blk["ln1"]["b"], EPS)
+        h = norm(t, blk["ln1"], p + "qkv")
         qkv = linear(quant_for(h, p + "qkv"), p + "qkv")
         a = ref.attn_full(quant_for(qkv, p + "attn"), dm.heads,
                           specs(p + "attn")[1].frac_bits)
         t = t + linear(quant_for(a, p + "proj"), p + "proj")
-        h = layernorm(t, blk["ln2"]["g"], blk["ln2"]["b"], EPS)
-        u = jax.nn.gelu(linear(quant_for(h, p + "fc1"), p + "fc1"),
-                        approximate=False)
+        h = norm(t, blk["ln2"], p + "fc1")
+        u = igelu(linear(quant_for(h, p + "fc1"), p + "fc1"), GELU_IN_FRAC,
+                  specs(p + "fc2")[1].frac_bits)
         t = t + linear(quant_for(u, p + "fc2"), p + "fc2")
-    return layernorm(t[:, 0], params["norm"]["g"], params["norm"]["b"], EPS)
+    return norm(t[:, 0], params["norm"], None)
 
 
 # ---------------------------------------------------------------------------
@@ -215,10 +241,16 @@ def export_graph(params: Params, qcfg: QuantConfig,
                           [f"{lname}.y"]))
         return f"{lname}.y"
 
-    def norm(src, p, out):
+    rf = res_frac(qcfg)
+
+    def norm(src, p, out, lname):
+        """Integer LayerNorm onto the grid of ``lname``'s input quantizer
+        (the final one, ``lname`` None, onto its own input grid)."""
         nodes.append(Node("layernorm", [src, const(out + "_g", p["g"]),
                                         const(out + "_b", p["b"])],
-                          [out], {"eps": EPS}))
+                          [out], {"eps": EPS, "in_frac": rf,
+                                  "out_frac": qcfg.layer(lname).act.frac_bits
+                                  if lname else rf}))
         return out
 
     nodes.append(Node("im2col", ["x"], ["cols"],
@@ -229,7 +261,7 @@ def export_graph(params: Params, qcfg: QuantConfig,
     t = "t0"
     for i, blk in enumerate(params["blocks"]):
         p = f"l{i}."
-        h = norm(t, blk["ln1"], p + "ln1")
+        h = norm(t, blk["ln1"], p + "ln1", p + "qkv")
         if qcfg.layer(p + "attn").act.total_bits > 8:
             raise ValueError("the integer attention takes 8-bit codes")
         qkv = act_quant(linear(act_quant(h, p + "qkv"), p + "qkv"),
@@ -239,14 +271,16 @@ def export_graph(params: Params, qcfg: QuantConfig,
                            "frac_bits": qcfg.layer(p + "attn").act.frac_bits}))
         o = linear(act_quant(p + "attn", p + "proj"), p + "proj")
         nodes.append(Node("add", [t, o], [p + "r1"]))
-        h = norm(p + "r1", blk["ln2"], p + "ln2")
+        h = norm(p + "r1", blk["ln2"], p + "ln2", p + "fc1")
         u = linear(act_quant(h, p + "fc1"), p + "fc1")
-        nodes.append(Node("gelu", [u], [p + "gelu"], {"approximate": False}))
+        nodes.append(Node("igelu", [u], [p + "gelu"],
+                          {"in_frac": GELU_IN_FRAC,
+                           "out_frac": qcfg.layer(p + "fc2").act.frac_bits}))
         o = linear(act_quant(p + "gelu", p + "fc2"), p + "fc2")
         nodes.append(Node("add", [p + "r1", o], [p + "r2"]))
         t = p + "r2"
     nodes.append(Node("cls_readout", [t], ["cls_out"]))
-    norm("cls_out", params["norm"], "features")
+    norm("cls_out", params["norm"], "features", None)
     g = Graph(nodes, ["x"], ["features"], inits, name=name)
     g.dtypes.update(dtypes)
     return g

@@ -72,9 +72,10 @@ def deployed(small):
 
 # -- the model against the plain reference ----------------------------------
 def test_qat_forward_matches_the_plain_reference(ref, small, qat):
-    """Linears and attention are exact, and LayerNorm and GELU are the
-    reference's float32 formulas: the features agree bit for bit.  The
-    reference one step lower in precision departs by far more."""
+    """Linears, attention, LayerNorm and GELU are exact integer arithmetic,
+    and each float value is one correctly rounded operation: the features
+    agree bit for bit.  The reference one step lower in precision departs
+    by far more."""
     cfg, params, x = small
     want = ref.features(params, x, cfg)
     got = qat
@@ -86,8 +87,8 @@ def test_qat_forward_matches_the_plain_reference(ref, small, qat):
 
 @pytest.mark.parametrize("datapath", ["f32", "int"])
 def test_deployed_graph_is_the_qat_model(small, qat, deployed, datapath):
-    """Every linear and the attention are exact on both datapaths, and the
-    float ops are the same functions in one program: bit for bit."""
+    """Every linear, the attention, LayerNorm and GELU are exact on both
+    datapaths: bit for bit."""
     _, _, x = small
     got = np.asarray(deployed[datapath](jnp.asarray(x)))
     np.testing.assert_array_equal(got, qat)
@@ -304,6 +305,94 @@ def test_gelu_approximate_attribute(approximate):
 def test_vit_ops_have_float_datatype_rules():
     from repro.core.datatypes import DATATYPE_RULES
 
-    for op in ("layernorm", "attn_full", "vit_tokens", "cls_readout"):
+    for op in ("layernorm", "igelu", "attn_full", "vit_tokens",
+               "cls_readout"):
         assert DATATYPE_RULES[op](None, [FixedPointSpec(8, 5, True)],
                                   None) is None
+
+
+# -- integer LayerNorm and GELU ----------------------------------------------
+def test_isqrt_is_the_floor_square_root():
+    from repro.core.graph import isqrt
+
+    rng = np.random.default_rng(0)
+    v = np.concatenate([np.arange(1, 1 << 16),
+                        rng.integers(1, 2 ** 31 - 1, 1 << 16),
+                        [2 ** 30, 2 ** 31 - 1]]).astype(np.int32)
+    r = np.asarray(jax.jit(isqrt)(jnp.asarray(v))).astype(np.int64)
+    assert ((r * r <= v) & ((r + 1) ** 2 > v)).all()
+
+
+def _rows(n=384):
+    """Residual-stream rows: spreads from 0.01 to 30, offsets, a constant
+    row, one outlier, values past the input codes' range."""
+    rng = np.random.default_rng(5)
+    x = (rng.normal(size=(300, n)) * rng.uniform(0.01, 30, (300, 1))
+         + rng.normal(size=(300, 1)) * 3)
+    x[0] = 0.7
+    x[1, 7] = 120.0
+    x[2] = np.linspace(-2000.0, 2000.0, n)
+    return x.astype(np.float32)
+
+
+@pytest.mark.parametrize("out_frac", [5, 13])
+def test_integer_layernorm_is_the_references(ref, out_frac):
+    """The program's LayerNorm gives the plain reference's bits on every
+    row, lies on its output grid, and within 0.004 + one output step of
+    float LayerNorm on rows of ordinary spread."""
+    from repro.core.graph import layernorm
+
+    x = _rows()
+    rng = np.random.default_rng(6)
+    g = rng.uniform(0.5, 1.5, 384).astype(np.float32)
+    b = rng.uniform(-0.25, 0.25, 384).astype(np.float32)
+    got = np.asarray(jax.jit(lambda x: layernorm(x, g, b, 1e-6, 13,
+                                                 out_frac))(x))
+    want = np.asarray(ref._layernorm(jnp.asarray(x), {"g": g, "b": b}, 13,
+                                     out_frac))
+    np.testing.assert_array_equal(got, want)
+    assert (got * 2.0 ** out_frac == np.round(got * 2.0 ** out_frac)).all()
+    xf = x[3:].astype(np.float64)
+    xc = xf - xf.mean(-1, keepdims=True)
+    fl = xc / np.sqrt((xc * xc).mean(-1, keepdims=True) + 1e-6) * g + b
+    spread = x[3:].std(-1) > 0.1
+    assert np.abs(got[3:][spread] - fl[spread]).max() < \
+        0.004 + 2.0 ** -out_frac
+
+
+def test_integer_layernorm_bits_do_not_depend_on_the_program():
+    """Integer inside: the same bits jitted whole, jitted on a transposed
+    copy, and op by op without jit."""
+    from repro.core.graph import layernorm
+
+    x = _rows()[:64]
+    g, b = np.full(384, 1.25, np.float32), np.full(384, 0.125, np.float32)
+
+    def ln(x):
+        return layernorm(x, g, b, 1e-6, 13, 5)
+
+    whole = np.asarray(jax.jit(ln)(x))
+    moved = np.asarray(jax.jit(lambda t: ln(t.T))(x.T))
+    with jax.disable_jit():
+        eager = np.asarray(ln(jnp.asarray(x)))
+    np.testing.assert_array_equal(whole, moved)
+    np.testing.assert_array_equal(whole, eager)
+
+
+def test_integer_gelu_is_the_references_and_near_gelu(ref):
+    """I-BERT's GELU: the plain reference's bits; within 0.022 of the exact
+    (erf) GELU before its output is rounded, and the identity and zero far
+    out."""
+    from repro.core.graph import igelu
+
+    x = jnp.linspace(-12.0, 12.0, 48001)
+    got = np.asarray(jax.jit(lambda x: igelu(x, 8, 5))(x))
+    np.testing.assert_array_equal(got, np.asarray(ref._gelu(x, 5)))
+    assert (got * 32 == np.round(got * 32)).all()
+    fine = np.asarray(igelu(x, 8, 17))
+    exact = np.asarray(jax.nn.gelu(x, approximate=False))
+    assert np.abs(fine - exact).max() < 0.022
+    far = np.asarray(igelu(jnp.asarray([-50.0, 50.0]), 8, 5))
+    np.testing.assert_array_equal(far, [0.0, 50.0])
+    with pytest.raises(ValueError, match="no int32 GELU"):
+        igelu(x, 10, 5)
