@@ -591,8 +591,7 @@ _EXECUTORS: Dict[str, Callable[..., jax.Array]] = {
     "embed": _ex_embed,
     "rmsnorm": _ex_rmsnorm,
     "silu": lambda node, x: jax.nn.silu(x),
-    "gelu": lambda node, x: jax.nn.gelu(
-        x, approximate=node.attrs.get("approximate", True)),
+    "gelu": lambda node, x: jax.nn.gelu(x),
     "attn_decode": _ex_attn_decode,
     "attn_prefill": _ex_attn_prefill,
     "layernorm": lambda node, x, g, b: layernorm(

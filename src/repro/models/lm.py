@@ -548,8 +548,7 @@ def _export_graph(params: Params, cfg: ArchConfig, *, decode: bool,
         if cfg.act == "gelu":
             matmul(h2q, w_init(f"{p}.w_up", bp["mlp"]["w_up"]["w"]),
                    f"{p}.up")
-            nodes.append(Node("gelu", [f"{p}.up"], [f"{p}.h"],
-                              {"approximate": True}))   # jax.nn.gelu's tanh
+            nodes.append(Node("gelu", [f"{p}.up"], [f"{p}.h"]))
         else:                            # swiglu
             matmul(h2q, w_init(f"{p}.w_gate", bp["mlp"]["w_gate"]["w"]),
                    f"{p}.gate")

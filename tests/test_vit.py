@@ -7,7 +7,6 @@ added to the graph IR."""
 from __future__ import annotations
 
 import importlib.util
-import math
 import os
 
 import jax
@@ -15,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.graph import Graph, Node, execute, im2col
+from repro.core.graph import im2col
 from repro.core.quant import FixedPointSpec, QuantConfig, fake_quant
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -280,26 +279,6 @@ def test_im2col_3x3_keeps_its_slice_path():
     want = np.concatenate([xp[:, i:i + 6, j:j + 6] for i in range(3)
                            for j in range(3)], axis=-1)
     np.testing.assert_array_equal(np.asarray(im2col(jnp.asarray(x))), want)
-
-
-@pytest.mark.parametrize("approximate", [True, False, None])
-def test_gelu_approximate_attribute(approximate):
-    """``approximate`` picks tanh (the default, lm-decode's) or erf (the
-    ViT's); either way the output is a float tensor."""
-    from repro.core.datatypes import InferDataTypes
-
-    x = jnp.linspace(-4.0, 4.0, 101)
-    attrs = {} if approximate is None else {"approximate": approximate}
-    g = Graph([Node("gelu", ["x"], ["y"], attrs)], ["x"], ["y"])
-    g.dtypes["x"] = FixedPointSpec(8, 5, True)
-    (y,) = execute(g, {"x": x})
-    want = jax.nn.gelu(x, approximate=approximate is not False)
-    np.testing.assert_array_equal(y, want)
-    if approximate is False:
-        erf = 0.5 * x * (1 + jax.scipy.special.erf(x / math.sqrt(2)))
-        np.testing.assert_allclose(y, erf, rtol=1e-6, atol=1e-6)
-        assert np.abs(y - jax.nn.gelu(x)).max() > 1e-4
-    assert InferDataTypes(g).dtypes["y"] is None
 
 
 def test_vit_ops_have_float_datatype_rules():
